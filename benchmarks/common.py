@@ -73,6 +73,10 @@ _force_devices()
 import jax
 import jax.numpy as jnp
 
+from repro.launch.runtime import cpu_child_env, enable_compile_cache
+
+enable_compile_cache()
+
 from repro.core.channel import sample_positions
 from repro.core.digital_twin import DTConfig, sample_v_max
 from repro.core.fl_round import FLConfig, FLState, run_training
@@ -216,18 +220,16 @@ def host_cores() -> int:
 def run_scaling_workers(module: str, devices=SCALING_DEVICES,
                         timeout: int = 1200) -> dict:
     """Spawn ``python -m {module} --scaling-worker D`` once per device
-    count, each child pinned to D forced host devices via XLA_FLAGS.
-    The worker prints one ``SCALING_ROWS {json}`` line mapping tier name
+    count, each child a CPU run pinned to D forced host devices
+    (``runtime.cpu_child_env``, which refuses when this process holds a
+    non-CPU backend: a chip belongs to one process).  The worker prints
+    one ``SCALING_ROWS {json}`` line mapping tier name
     → {rate, parity_max_rel, ...}; returns {D: rows}."""
     out = {}
     for d in devices:
-        env = dict(os.environ)
+        env = cpu_child_env(d)
         for k in ("REPRO_FORCE_DEVICES", "REPRO_MESH_DEVICES"):
             env.pop(k, None)
-        keep = [f for f in env.get("XLA_FLAGS", "").split()
-                if not f.startswith("--xla_force_host_platform_device_count")]
-        env["XLA_FLAGS"] = " ".join(
-            keep + [f"--xla_force_host_platform_device_count={d}"])
         env[_DEVICES_APPLIED_ENV] = str(d)   # flags set directly: no re-exec
         proc = subprocess.run(
             [sys.executable, "-m", module, "--scaling-worker", str(d)],

@@ -15,6 +15,8 @@ micro-benchmarks.  Prints ``name,us_per_call,derived`` CSV.
         # suite loads jax — every suite then runs sharded
 
 Unknown ``--only`` names are an error (they used to silently run nothing).
+A suite that raises is reported as an ``ERROR`` row, the remaining suites
+still run, and the process then exits non-zero.
 The summary (stdout + ``runs/bench/summary.csv``) ends with ``#``-comment
 rows recording the device count and per-suite wall-clock seconds.
 """
@@ -54,6 +56,7 @@ def main() -> None:
     print("name,us_per_call,derived")
     rows = []
     suite_walls = []
+    failed = []
     for suite in SUITES:
         if suite not in wanted:
             continue
@@ -85,9 +88,10 @@ def main() -> None:
                 line = f"{name},{us:.1f},{derived}"
                 print(line, flush=True)
                 rows.append(line)
-        except Exception:  # noqa: BLE001
+        except Exception:  # noqa: BLE001 — report, run the rest, exit 1
             print(f"{suite},NaN,ERROR", flush=True)
             traceback.print_exc()
+            failed.append(suite)
         suite_walls.append((suite, time.perf_counter() - t_suite))
 
     import jax
@@ -101,6 +105,8 @@ def main() -> None:
         f.write("name,us_per_call,derived\n")
         f.write("\n".join(rows) + "\n")
         f.write("\n".join(footer) + "\n")
+    if failed:
+        sys.exit(f"benchmark suite(s) failed: {','.join(failed)}")
 
 
 if __name__ == "__main__":

@@ -40,6 +40,10 @@ import time
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
+from repro.launch.runtime import enable_compile_cache  # noqa: E402
+
+enable_compile_cache()
+
 import numpy as np
 
 from repro.core.stackelberg import GameConfig

@@ -16,6 +16,10 @@ import sys
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
+from repro.launch.runtime import enable_compile_cache  # noqa: E402
+
+enable_compile_cache()
+
 from repro.launch import train as train_mod
 from repro.models.config import ATTN, BlockSpec, ModelConfig
 

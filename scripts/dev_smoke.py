@@ -1,8 +1,11 @@
 """Dev smoke: tiny variants of each family, forward + loss + decode."""
 import jax, jax.numpy as jnp
+from repro.launch.runtime import cpu_child_env, enable_compile_cache
 from repro.models import (ATTN, CROSS, MAMBA, MOE, SHARED_ATTN, BlockSpec,
                           ModelConfig, decode_step, init_caches, init_params,
                           loss_fn, prefill)
+
+enable_compile_cache()
 
 def run(name, cfg, batch):
     key = jax.random.PRNGKey(0)
@@ -223,7 +226,8 @@ print(f"fault grid OK: 2 scenarios x S=2 x R=2, 1 trace, "
 # multi-device smoke (ISSUE 8): 4 forced host devices, a C=3 × K=5 sweep
 # on the 2D (cfg, draw) mesh — non-divisible axes pad + slice back, the
 # grid still traces exactly ONCE, and cells match per-instance solves.
-# Subprocess: the XLA device count is fixed at jax import.
+# Subprocess: the XLA device count is fixed at jax import, and the child is
+# a CPU run (cpu_child_env refuses while this process holds a chip).
 import os, pathlib, subprocess, sys
 _root = pathlib.Path(__file__).resolve().parents[1]
 _MD_SMOKE = r"""
@@ -247,13 +251,9 @@ rel = abs(float(en[1, 2]) - ref) / max(abs(ref), 1e-12)
 assert rel <= 1e-5, rel
 print("MULTIDEVICE_SMOKE_OK")
 """
-_env = dict(os.environ)
+_env = cpu_child_env(4)
 _env["PYTHONPATH"] = (str(_root / "src") + os.pathsep +
                       _env.get("PYTHONPATH", ""))
-_env["XLA_FLAGS"] = " ".join(
-    [f for f in _env.get("XLA_FLAGS", "").split()
-     if not f.startswith("--xla_force_host_platform_device_count")]
-    + ["--xla_force_host_platform_device_count=4"])
 _proc = subprocess.run([sys.executable, "-c", _MD_SMOKE], env=_env,
                        capture_output=True, text=True, timeout=420)
 assert _proc.returncode == 0, _proc.stderr[-2000:]

@@ -44,11 +44,13 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
+from .noma import exp2_m1, log2_1p
+
 LN2 = 0.6931471805599453
 
 
 def _rate(p, f_eff, bandwidth):
-    return bandwidth * jnp.log2(1.0 + p * f_eff)
+    return bandwidth * log2_1p(p * f_eff)
 
 
 def _p_floor(d, g, f_eff, bandwidth, p_min):
@@ -66,7 +68,7 @@ def _p_floor(d, g, f_eff, bandwidth, p_min):
     big = expo > 60.0            # 2**60 already exceeds any reachable p_max
     f_ok = f_eff > 1e-30
     f_safe = jnp.where(f_ok, f_eff, 1.0)
-    need_raw = (2.0 ** jnp.where(big, 0.0, expo) - 1.0) / f_safe
+    need_raw = exp2_m1(jnp.where(big, 0.0, expo)) / f_safe
     need = jnp.where(f_ok & ~big, need_raw, 1e30)
     return jnp.maximum(p_min, need)
 

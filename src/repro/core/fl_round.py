@@ -58,7 +58,6 @@ from typing import Callable, Dict, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from ..data.federated import FedData
@@ -571,10 +570,11 @@ def _batched_training_jit(phys, states, data, ops, fops, *, rounds,
         # each device scans its local seed block independently (no
         # collectives — the trajectories never talk to each other)
         dspec = P(game_mesh.DRAW_AXIS) if data_batched else P()
-        run = shard_map(run, mesh=game_mesh.mesh_1d(shards),
-                        in_specs=(P(), P(game_mesh.DRAW_AXIS), dspec,
-                                  P(), P()),
-                        out_specs=P(game_mesh.DRAW_AXIS), check_rep=False)
+        run = jax.shard_map(run, mesh=game_mesh.mesh_1d(shards),
+                            in_specs=(P(), P(game_mesh.DRAW_AXIS), dspec,
+                                      P(), P()),
+                            out_specs=P(game_mesh.DRAW_AXIS),
+                            check_vma=False)
     return run(phys, states, data, ops, fops)
 
 
@@ -747,12 +747,10 @@ def _sweep_training_jit(phys, states, data, ops, fops, *, rounds,
         dspec = {"shared": P(), "seed": P(game_mesh.DRAW_AXIS),
                  "config": P(game_mesh.CFG_AXIS)}[data_mode]
         cfg_p = P(game_mesh.CFG_AXIS)
-        grid = shard_map(grid, mesh=game_mesh.mesh_2d(dc, dk),
-                         in_specs=(cfg_p,
-                                   P(game_mesh.CFG_AXIS, game_mesh.DRAW_AXIS),
-                                   dspec, cfg_p, cfg_p),
-                         out_specs=P(game_mesh.CFG_AXIS, game_mesh.DRAW_AXIS),
-                         check_rep=False)
+        grid_p = P(game_mesh.CFG_AXIS, game_mesh.DRAW_AXIS)
+        grid = jax.shard_map(grid, mesh=game_mesh.mesh_2d(dc, dk),
+                             in_specs=(cfg_p, grid_p, dspec, cfg_p, cfg_p),
+                             out_specs=grid_p, check_vma=False)
     return grid(phys, states, data, ops, fops)
 
 

@@ -111,7 +111,7 @@ def fixed_point_step(x, theta):
     # --- Dinkelbach ratio at p_new's own interference -------------------
     intf2 = suffix_interference(p_new * h2, mode="ref")
     f_eff2 = h2 / (intf2 + phys.sigma2)
-    rates = phys.bandwidth * jnp.log2(1.0 + p_new * f_eff2)
+    rates = phys.bandwidth * noma.log2_1p(p_new * f_eff2)
     u = p_new * d_bits
     u_ok = u > 1e-30
     q_new = jnp.where(u_ok, rates / jnp.where(u_ok, u, jnp.ones((), dtype)),

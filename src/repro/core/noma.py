@@ -12,10 +12,33 @@ as static.
 """
 from __future__ import annotations
 
+import math
+
 import jax
 import jax.numpy as jnp
 
+from ..kernels.ref import sic_suffix_ref
 from .channel import BANDWIDTH_HZ, noise_power
+
+# The TPU v5e computes f32 log/exp with fast approximations by default
+# (log off by up to ~7e-5 relative on the chip), so every transcendental of
+# the game asks XLA for its most accurate implementation instead; on the
+# CPU backend the compiled program is unchanged.
+_ACCURATE = jax.lax.AccuracyMode.HIGHEST
+_LN2 = math.log(2.0)
+
+
+def log2_1p(x):
+    """``log2(1 + x)`` at the backend's highest accuracy."""
+    return jax.lax.log(1.0 + jnp.asarray(x), accuracy=_ACCURATE) / _LN2
+
+
+def exp2_m1(x):
+    """``2 ** x - 1`` at the backend's highest accuracy.  expm1, not
+    ``exp2(x) - 1``: the Dinkelbach rate floor (2**x - 1)/F has x far
+    below 1 for a client with a loose deadline, where the subtraction
+    would cancel all but a few of the significant digits."""
+    return jax.lax.expm1(jnp.asarray(x) * _LN2, accuracy=_ACCURATE)
 
 
 def sic_order(h2):
@@ -32,17 +55,17 @@ def noma_rates(p, h2_sorted, bandwidth=BANDWIDTH_HZ, sigma2=None):
     if sigma2 is None:
         sigma2 = noise_power(bandwidth)
     rx = p * h2_sorted
-    # reverse-exclusive cumulative sum: interference from later-decoded clients
-    intf = jnp.flip(jnp.cumsum(jnp.flip(rx))) - rx
+    # exclusive suffix sum: interference from later-decoded clients
+    intf = sic_suffix_ref(rx)
     sinr = rx / (intf + sigma2)
-    return bandwidth * jnp.log2(1.0 + sinr)
+    return bandwidth * log2_1p(sinr)
 
 
 def sum_capacity(p, h2, bandwidth=BANDWIDTH_HZ, sigma2=None):
     """MAC sum capacity B·log2(1 + Σ p|h|²/σ²) — SIC achieves it exactly."""
     if sigma2 is None:
         sigma2 = noise_power(bandwidth)
-    return bandwidth * jnp.log2(1.0 + jnp.sum(p * h2) / sigma2)
+    return bandwidth * log2_1p(jnp.sum(p * h2) / sigma2)
 
 
 def oma_rates(p, h2, bandwidth=BANDWIDTH_HZ, sigma2_full=None):
@@ -52,7 +75,7 @@ def oma_rates(p, h2, bandwidth=BANDWIDTH_HZ, sigma2_full=None):
     if sigma2_full is None:
         sigma2_full = noise_power(bandwidth)
     sigma2 = sigma2_full / n           # noise scales with sub-band width
-    return bw * jnp.log2(1.0 + p * h2 / sigma2)
+    return bw * log2_1p(p * h2 / sigma2)
 
 
 def tx_latency(d_bits, rates):

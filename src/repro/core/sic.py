@@ -92,6 +92,7 @@ import jax.numpy as jnp
 
 from ..kernels.ops import sic_suffix_sum
 from .dinkelbach import dinkelbach_power, successive_power
+from .noma import log2_1p
 from .tracking import TRACE_COUNTS
 
 SIC_MODES = ("sequential", "blocked", "blocked_interpret", "blocked_pallas")
@@ -180,7 +181,7 @@ def successive_power_blocked(h2_sorted, d, g, bandwidth, sigma2, p_min,
     # coupling even when p is already stationary
     intf = suffix_interference(p * h2_sorted, mode=suffix_mode)
     f_eff = h2_sorted / (intf + sigma2)
-    rate = bandwidth * jnp.log2(1.0 + p * f_eff)
+    rate = bandwidth * log2_1p(p * f_eff)
     q = rate / jnp.maximum(p * d_v, 1e-30)
     if return_sweeps:
         return p, q, sweeps

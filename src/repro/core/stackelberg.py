@@ -56,7 +56,6 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from . import noma
@@ -429,10 +428,10 @@ def _batched_equilibrium_jit(phys, h2_batch, D_batch, v_max_batch, epsilon,
 
     if shards > 1:
         # one independent while_loop per device over its local K block
-        vsolve = shard_map(vsolve, mesh=game_mesh.mesh_1d(shards),
-                           in_specs=(P(), P(_DRAW), P(_DRAW), P(_DRAW),
-                                     P(), P()),
-                           out_specs=P(_DRAW), check_rep=False)
+        vsolve = jax.shard_map(vsolve, mesh=game_mesh.mesh_1d(shards),
+                               in_specs=(P(), P(_DRAW), P(_DRAW), P(_DRAW),
+                                         P(), P()),
+                               out_specs=P(_DRAW), check_vma=False)
     return vsolve(phys, h2_batch, D_batch, v_max_batch, epsilon, tol)
 
 
@@ -453,10 +452,11 @@ def _sweep_equilibrium_jit(phys, h2_cbn, D_cbn, v_max_cbn, epsilon_c, tol,
     dc, dk = grid_shards
     if dc * dk > 1:
         # 2D (cfg, draw) mesh: each device owns a [C/dc, K/dk] grid tile
-        sweep = shard_map(sweep, mesh=game_mesh.mesh_2d(dc, dk),
-                          in_specs=(P(_CFG), P(_CFG, _DRAW), P(_CFG, _DRAW),
-                                    P(_CFG, _DRAW), P(_CFG), P()),
-                          out_specs=P(_CFG, _DRAW), check_rep=False)
+        grid = P(_CFG, _DRAW)
+        sweep = jax.shard_map(sweep, mesh=game_mesh.mesh_2d(dc, dk),
+                              in_specs=(P(_CFG), grid, grid, grid, P(_CFG),
+                                        P()),
+                              out_specs=grid, check_vma=False)
     return sweep(phys, h2_cbn, D_cbn, v_max_cbn, epsilon_c, tol)
 
 
@@ -738,13 +738,13 @@ def _oma_body(cfg, h2_sorted, D, v_max, epsilon, inner: str,
 
     p, q = jax.vmap(solve)(h2_sorted, g_n)
     if tdma:
-        rates = cfg.bandwidth * jnp.log2(1.0 + p * h2_sorted / cfg.sigma2)
+        rates = cfg.bandwidth * noma.log2_1p(p * h2_sorted / cfg.sigma2)
         t_own = noma.tx_latency(cfg.model_bits, rates)  # own-slot airtime
         if mask is not None:
             t_own = jnp.where(mask, t_own, jnp.zeros((), dtype))
         t_com = jnp.sum(t_own) * jnp.ones_like(t_own)   # sequential round
     else:
-        rates = bw * jnp.log2(1.0 + p * h2_sorted / s2)  # == oma_rates @ n_eff
+        rates = bw * noma.log2_1p(p * h2_sorted / s2)  # == oma_rates @ n_eff
         t_own = t_com = noma.tx_latency(cfg.model_bits, rates)
         if mask is not None:
             t_own = t_com = jnp.where(mask, t_own, jnp.zeros((), dtype))
@@ -788,10 +788,10 @@ def _batched_random_jit(phys, keys, h2, D, v_max, epsilon, inner, shards=1):
         return jax.vmap(body)(kk, h2_b, d_b, vm_b)
 
     if shards > 1:
-        vbody = shard_map(vbody, mesh=game_mesh.mesh_1d(shards),
-                          in_specs=(P(), P(_DRAW), P(_DRAW), P(_DRAW),
-                                    P(_DRAW), P()),
-                          out_specs=P(_DRAW), check_rep=False)
+        vbody = jax.shard_map(vbody, mesh=game_mesh.mesh_1d(shards),
+                              in_specs=(P(), P(_DRAW), P(_DRAW), P(_DRAW),
+                                        P(_DRAW), P()),
+                              out_specs=P(_DRAW), check_vma=False)
     return vbody(phys, keys, h2, D, v_max, epsilon)
 
 
@@ -814,10 +814,11 @@ def _sweep_random_jit(phys, keys, h2, D, v_max, epsilon_c, inner,
 
     dc, dk = grid_shards
     if dc * dk > 1:
-        sweep = shard_map(sweep, mesh=game_mesh.mesh_2d(dc, dk),
-                          in_specs=(P(_CFG), P(_DRAW), P(_CFG, _DRAW),
-                                    P(_CFG, _DRAW), P(_CFG, _DRAW), P(_CFG)),
-                          out_specs=P(_CFG, _DRAW), check_rep=False)
+        grid = P(_CFG, _DRAW)
+        sweep = jax.shard_map(sweep, mesh=game_mesh.mesh_2d(dc, dk),
+                              in_specs=(P(_CFG), P(_DRAW), grid, grid, grid,
+                                        P(_CFG)),
+                              out_specs=grid, check_vma=False)
     return sweep(phys, keys, h2, D, v_max, epsilon_c)
 
 
@@ -842,9 +843,10 @@ def _batched_oma_jit(phys, h2, D, v_max, epsilon, inner, tdma, shards=1):
         return jax.vmap(body)(h2_b, d_b, vm_b)
 
     if shards > 1:
-        vbody = shard_map(vbody, mesh=game_mesh.mesh_1d(shards),
-                          in_specs=(P(), P(_DRAW), P(_DRAW), P(_DRAW), P()),
-                          out_specs=P(_DRAW), check_rep=False)
+        vbody = jax.shard_map(vbody, mesh=game_mesh.mesh_1d(shards),
+                              in_specs=(P(), P(_DRAW), P(_DRAW), P(_DRAW),
+                                        P()),
+                              out_specs=P(_DRAW), check_vma=False)
     return vbody(phys, h2, D, v_max, epsilon)
 
 
@@ -862,10 +864,10 @@ def _sweep_oma_jit(phys, h2, D, v_max, epsilon_c, inner, tdma,
 
     dc, dk = grid_shards
     if dc * dk > 1:
-        sweep = shard_map(sweep, mesh=game_mesh.mesh_2d(dc, dk),
-                          in_specs=(P(_CFG), P(_CFG, _DRAW), P(_CFG, _DRAW),
-                                    P(_CFG, _DRAW), P(_CFG)),
-                          out_specs=P(_CFG, _DRAW), check_rep=False)
+        grid = P(_CFG, _DRAW)
+        sweep = jax.shard_map(sweep, mesh=game_mesh.mesh_2d(dc, dk),
+                              in_specs=(P(_CFG), grid, grid, grid, P(_CFG)),
+                              out_specs=grid, check_vma=False)
     return sweep(phys, h2, D, v_max, epsilon_c)
 
 

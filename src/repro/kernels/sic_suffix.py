@@ -18,9 +18,16 @@ strictly-lower-triangular ones matrix ([L]·[L×L]: row k contributes to
 column i iff k > i) — no flips or cumsums inside the kernel, so the same
 body lowers on TPU and runs under interpret mode on CPU.
 
-Layout: w [B, N] → s [B, N]; f32 accumulation; N is zero-padded up to a
-block multiple by the wrapper (trailing zeros contribute nothing to any
-real element's suffix).
+Layout: w [B, N] → s [B, N]; f32 accumulation.  Each grid step moves a
+``[ROWS, block]`` tile (``ROWS`` = 8, the TPU sublane count), so B is
+zero-padded up to a ``ROWS`` multiple and N up to a ``block``
+multiple by the wrapper, and both pads are sliced off the result
+(trailing zeros contribute nothing to any real element's suffix, and a
+padded row is never read back).  The in-block product is a 2-D
+``[ROWS, L] @ [L, L]`` MXU matmul at ``HIGHEST`` precision — the TPU's
+default f32 dot is a single bf16 pass, which would cost ~3 significant
+digits of the interference sum.  The carry scratch holds one running
+total per row.
 
 Masked-tail contract (ragged-N serving): the allocation service pads
 variable-N requests with zero-gain clients, so w = p·|h|² carries an
@@ -40,6 +47,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+ROWS = 8    # rows per grid step: the TPU's f32 sublane count
+
 
 def _suffix_kernel(w_ref, o_ref, carry_ref, *, block: int):
     ci = pl.program_id(1)
@@ -48,27 +57,29 @@ def _suffix_kernel(w_ref, o_ref, carry_ref, *, block: int):
     def _init():
         carry_ref[...] = jnp.zeros_like(carry_ref)
 
-    w = w_ref[0].astype(jnp.float32)                      # [L]
+    w = w_ref[...].astype(jnp.float32)                    # [R, L]
     # strict[k, i] = 1 iff k > i : w @ strict == exclusive in-block suffix
     ks = jax.lax.broadcasted_iota(jnp.int32, (block, block), 0)
     is_ = jax.lax.broadcasted_iota(jnp.int32, (block, block), 1)
     strict = (ks > is_).astype(jnp.float32)
-    carry = carry_ref[0, 0]                               # Σ of later blocks
-    s = jnp.dot(w, strict, preferred_element_type=jnp.float32) + carry
-    carry_ref[0, 0] = carry + jnp.sum(w)
-    o_ref[0] = s.astype(o_ref.dtype)
+    carry = carry_ref[...]                                # [R, 1] later blocks
+    s = jnp.dot(w, strict, preferred_element_type=jnp.float32,
+                precision=jax.lax.Precision.HIGHEST) + carry
+    carry_ref[...] = carry + jnp.sum(w, axis=1, keepdims=True)
+    o_ref[...] = s.astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("block", "interpret"))
 def sic_suffix_pallas(w, block: int = 128, interpret: bool = True):
     """w: [B, N] → exclusive suffix sums [B, N] (s[b, n] = Σ_{j>n} w[b, j]).
 
-    ``interpret=True`` executes on CPU for validation; on TPU pass False.
+    ``interpret=True`` executes on CPU for validation; on TPU pass False
+    (``block`` must then be a multiple of 128).
     """
     b, n = w.shape
-    pad = (-n) % block
-    wp = jnp.pad(w, ((0, 0), (0, pad))) if pad else w
-    nc = wp.shape[1] // block
+    pad_b, pad_n = (-b) % ROWS, (-n) % block
+    wp = jnp.pad(w, ((0, pad_b), (0, pad_n))) if pad_b or pad_n else w
+    nb, nc = wp.shape[0] // ROWS, wp.shape[1] // block
 
     kern = functools.partial(_suffix_kernel, block=block)
     kwargs = {}
@@ -77,14 +88,14 @@ def sic_suffix_pallas(w, block: int = 128, interpret: bool = True):
             dimension_semantics=("parallel", "arbitrary"))
     out = pl.pallas_call(
         kern,
-        grid=(b, nc),
+        grid=(nb, nc),
         # blocks are visited right-to-left: grid step j touches block
         # nc-1-j, so the carry accumulates the suffix of later blocks
-        in_specs=[pl.BlockSpec((1, block), lambda i, j: (i, nc - 1 - j))],
-        out_specs=pl.BlockSpec((1, block), lambda i, j: (i, nc - 1 - j)),
+        in_specs=[pl.BlockSpec((ROWS, block), lambda i, j: (i, nc - 1 - j))],
+        out_specs=pl.BlockSpec((ROWS, block), lambda i, j: (i, nc - 1 - j)),
         out_shape=jax.ShapeDtypeStruct(wp.shape, w.dtype),
-        scratch_shapes=[pltpu.VMEM((1, 1), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((ROWS, 1), jnp.float32)],
         interpret=interpret,
         **kwargs,
     )(wp)
-    return out[:, :n] if pad else out
+    return out[:b, :n] if pad_b or pad_n else out

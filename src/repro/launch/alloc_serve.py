@@ -119,7 +119,6 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from ..core.stackelberg import (GameConfig, _oma_body, _random_body, _solve,
@@ -193,9 +192,9 @@ def _serve_batch_jit(phys, keys, h2, D, v_max, eps, mask, tol, scheme,
 
     if shards > 1:
         d = P(game_mesh.DRAW_AXIS)
-        batch = shard_map(batch, mesh=game_mesh.mesh_1d(shards),
-                          in_specs=(d,) * 7 + (P(),), out_specs=d,
-                          check_rep=False)
+        batch = jax.shard_map(batch, mesh=game_mesh.mesh_1d(shards),
+                              in_specs=(d,) * 7 + (P(),), out_specs=d,
+                              check_vma=False)
     return batch(phys, keys, h2, D, v_max, eps, mask, tol)
 
 

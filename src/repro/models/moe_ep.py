@@ -26,7 +26,6 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from .config import ModelConfig
 
@@ -95,13 +94,13 @@ def moe_forward_ep(p, x, cfg: ModelConfig, mesh: Mesh):
 
     tok_spec = P(axes, None)
     has_gate = "w_gate" in p
-    fn = shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=(tok_spec, P(None, None), P("model", None, None),
                   P("model", None, None),
                   P("model", None, None) if has_gate else None),
         out_specs=(tok_spec, P()),
-        check_rep=False)
+        check_vma=False)
     xt = x.reshape(t, d)
     out, aux = fn(xt, p["router"], p["w_in"], p["w_out"],
                   p.get("w_gate"))

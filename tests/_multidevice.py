@@ -13,18 +13,15 @@ import os
 import subprocess
 import sys
 
-DEVICE_PREFIX = "--xla_force_host_platform_device_count"
+from repro.launch.runtime import cpu_child_env
 
 
 def forced_device_env(devices: int) -> dict:
-    """A subprocess env with ``devices`` forced host devices: repo ``src``
-    on PYTHONPATH, any stale device-count flag/override stripped."""
-    env = dict(os.environ)
+    """A subprocess env with ``devices`` forced host CPU devices: repo
+    ``src`` on PYTHONPATH, any stale device-count flag/override stripped."""
+    env = cpu_child_env(devices)
     env["PYTHONPATH"] = (os.path.join(os.path.dirname(__file__), "..", "src")
                          + os.pathsep + env.get("PYTHONPATH", ""))
-    keep = [f for f in env.get("XLA_FLAGS", "").split()
-            if not f.startswith(DEVICE_PREFIX)]
-    env["XLA_FLAGS"] = " ".join(keep + [f"{DEVICE_PREFIX}={devices}"])
     for k in ("REPRO_MESH_DEVICES", "REPRO_FORCE_DEVICES"):
         env.pop(k, None)
     return env

@@ -332,7 +332,7 @@ _POS = jnp.asarray([True])
 _NEG = jnp.asarray([False])
 
 
-@settings(max_examples=15)
+@settings(max_examples=15, deadline=None)
 @given(st.integers(min_value=1, max_value=40),
        st.integers(min_value=0, max_value=40),
        st.integers(min_value=1, max_value=6))
@@ -350,7 +350,7 @@ def test_reputation_strictly_decreases_on_detection(pi0, ni0, k):
         z = z_new
 
 
-@settings(max_examples=15)
+@settings(max_examples=15, deadline=None)
 @given(st.integers(min_value=1, max_value=10),
        st.integers(min_value=1, max_value=25))
 def test_reputation_recovers_boundedly_after_attack_stops(n_attack, n_rec):

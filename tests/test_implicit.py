@@ -16,7 +16,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.experimental import enable_x64
 
 from repro.core.channel import sample_channel_gains, sample_positions
 from repro.core.implicit import equilibrium_implicit
@@ -70,7 +69,7 @@ class TestGradcheck:
                              ids=[s[0] for s in SCHEMES])
     @pytest.mark.parametrize("sic_mode", SIC_MODES)
     def test_h2_vmax_eps_gradients_vs_fd(self, scheme, vmax, eps, sic_mode):
-        with enable_x64():
+        with jax.enable_x64():
             cfg = GameConfig(sic_mode=sic_mode)
             h2 = _draw()
             D = jnp.full((N,), 500.0, jnp.float64)
@@ -114,7 +113,7 @@ class TestGradcheck:
     def test_physics_gradients_vs_fd(self):
         """t_max / model_bits enter through the fixed point only — the
         purest IFT path (no direct ``_finish`` dependence for t_max)."""
-        with enable_x64():
+        with jax.enable_x64():
             cfg = GameConfig()
             h2 = _draw()
             D = jnp.full((N,), 500.0, jnp.float64)
@@ -141,7 +140,7 @@ class TestGradcheck:
     def test_energy_has_zero_epsilon_gradient(self):
         """ε never enters the leader fixed point: dE/dε ≡ 0 by
         construction (only latency moves)."""
-        with enable_x64():
+        with jax.enable_x64():
             cfg = GameConfig()
             h2 = _draw()
             g = jax.grad(lambda e: equilibrium_implicit(

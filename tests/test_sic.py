@@ -144,6 +144,7 @@ def test_successive_power_any_dispatch_and_validation():
     ((3, 64), 32),          # exact multiple
     ((2, 257), 128),        # non-power-of-two tail
     ((1, 512), 128),        # multi-block carry chain
+    ((10, 300), 128),       # several 8-row blocks, padded rows and tail
 ])
 def test_suffix_kernel_matches_ref(shape, block):
     w = jax.random.uniform(jax.random.PRNGKey(shape[1]), shape) * 1e-3
@@ -170,6 +171,16 @@ def test_suffix_kernel_under_vmap_and_modes():
                                                      block=64) - ref))) < tol
     # exclusive: last element sees zero interference
     assert float(jnp.max(jnp.abs(ref[:, -1]))) == 0.0
+
+
+@pytest.mark.parametrize("mode", ["pallas", "bogus"])
+def test_suffix_mode_never_falls_back(mode):
+    """Off the TPU an explicit ``pallas`` request raises instead of
+    quietly running the interpreter; an unknown mode is an error."""
+    w = jnp.ones((2, 16))
+    err = RuntimeError if mode == "pallas" else ValueError
+    with pytest.raises(err):
+        sic_suffix_sum(w, mode=mode)
 
 
 # ---------------------------------------------------------------------------
