@@ -29,8 +29,9 @@ Per-(bucket, scheme) circuit breakers contain a sick executable:
 ``breaker_threshold`` consecutive bad batches (non-finite outputs,
 watchdog trips, dispatch failures) trip it OPEN → submissions fast-fail
 → after ``breaker_cooldown_s`` a HALF_OPEN probe either closes it or
-re-opens.  ``service.health()`` snapshots queues, breakers, counters
-and per-priority latency percentiles.
+re-opens.  ``service.health()`` snapshots queues, breakers, counters,
+per-priority latency percentiles and those of each request stage (queued,
+packing, dispatch call, in flight, wait for the device, readback).
 
     PYTHONPATH=src python examples/serve_allocation.py
 """
@@ -104,3 +105,7 @@ print(f"  health: counters={health['counters']}")
 print(f"          breakers={health['breakers']}")
 print(f"          latency by priority (ms) = "
       f"{health['latency_by_priority_ms']}")
+print("          stages of a request, p50 / p99 (ms):")
+for name, row in health["stages"].items():
+    print(f"            {name:>12}: {row['p50_ms']:8.2f} / "
+          f"{row['p99_ms']:8.2f}")

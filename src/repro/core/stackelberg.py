@@ -64,7 +64,7 @@ from .channel import BANDWIDTH_HZ, noise_power
 from .dinkelbach import dinkelbach_power
 from .sic import SIC_MODES, successive_power_any
 # re-exported from .tracking (the historical import site for both)
-from .tracking import TRACE_COUNTS, reset_trace_counts
+from .tracking import TRACE_COUNTS, reset_trace_counts, span
 
 TAU = 2e-28  # effective capacitance coefficient (Table I / [22])
 
@@ -312,9 +312,13 @@ def _leader_iteration(cfg, h2_sorted, D, v, f, inner: str,
     perturbs real clients because p·|h|² = 0 in every suffix sum."""
     t_cmp = local_compute_latency(cfg.cycles_per_sample, v, D, f)
     g_n = jnp.maximum(cfg.t_max - t_cmp, 1e-3)        # rate-floor slack
-    p, q = successive_power_any(h2_sorted, cfg.model_bits, g_n,
-                                cfg.bandwidth, cfg.sigma2, cfg.p_min,
-                                cfg.p_max, inner=inner, sic_mode=sic_mode)
+    # the scope names the SIC chain's ops in the compiled module, so a
+    # device trace can attribute their time
+    with jax.named_scope("sic_power"):
+        p, q = successive_power_any(h2_sorted, cfg.model_bits, g_n,
+                                    cfg.bandwidth, cfg.sigma2, cfg.p_min,
+                                    cfg.p_max, inner=inner,
+                                    sic_mode=sic_mode)
     rates = noma.noma_rates(p, h2_sorted, cfg.bandwidth, cfg.sigma2)
     t_com = noma.tx_latency(cfg.model_bits, rates)
     a_n = jnp.maximum(cfg.t_max - t_com, 1e-3)
@@ -587,13 +591,15 @@ def batched_equilibrium(cfg: GameConfig, h2_batch, D_batch, v_max_batch,
     to one compile + one device dispatch, and the K axis is sharded
     across available devices (no-op on one device).
     """
-    phys, h2, D, vm, eps, tol, shards, k = _canon_batch(
-        cfg, h2_batch, D_batch, v_max_batch, epsilon, tol)
-    out = _batched_equilibrium_jit(phys, h2, D, vm, eps, tol,
-                                   max_iter=max_iter,
-                                   inner=cfg.dinkelbach_inner,
-                                   sic_mode=cfg.sic_mode, shards=shards)
-    return _unpad(out, k)
+    with span("equilibrium.canon"):
+        phys, h2, D, vm, eps, tol, shards, k = _canon_batch(
+            cfg, h2_batch, D_batch, v_max_batch, epsilon, tol)
+    with span("equilibrium.launch"):
+        out = _batched_equilibrium_jit(phys, h2, D, vm, eps, tol,
+                                       max_iter=max_iter,
+                                       inner=cfg.dinkelbach_inner,
+                                       sic_mode=cfg.sic_mode, shards=shards)
+        return _unpad(out, k)
 
 
 def sweep_equilibrium(configs: Sequence[GameConfig], h2_batch, D, v_max,

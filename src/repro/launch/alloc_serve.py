@@ -62,10 +62,10 @@ vocabulary:
 
 **Per-request SLA.**  ``AllocRequest.deadline_s`` (submit→result wall
 budget) and ``AllocRequest.priority`` (higher = more important) drive
-three scheduler mechanisms: (1) admission control — an EWMA of measured
-per-(bucket, scheme) dispatch latency predicts the queue wait; a request
-whose deadline the prediction already busts is rejected FAST, before it
-wastes a batch lane; (2) bounded queues — when ``max_queue`` is set the
+three scheduler mechanisms: (1) admission control — an EWMA of each
+(bucket, scheme) batch's time from dispatch to ready-at-reap predicts the
+queue wait; a request whose deadline the prediction already busts is
+rejected FAST, before it wastes a batch lane; (2) bounded queues — when ``max_queue`` is set the
 service stops blocking the producer (PR-8 behavior) and instead defers
 dispatch while the in-flight window is full, opportunistically retiring
 ready batches (``jax.Array.is_ready`` polling), and sheds the
@@ -94,7 +94,11 @@ ill-health, so it doesn't open the breaker by default), fast-fail
 submissions while open, move to HALF_OPEN
 after ``breaker_cooldown_s`` and close again on the next healthy batch.
 ``health()`` snapshots queue depths, breaker states, every resilience
-counter and per-priority p50/p99 latency.
+counter, per-priority p50/p99 latency and p50/p99 of each request stage
+(``STAGES``: queued, packing, the dispatch call, in flight, the reap's
+wait for the device, readback).  ``submit``, the packing, the dispatch
+call and the reap are host spans (``repro.core.tracking.span``), joined
+through ``rid`` → ``AllocResult.stages["batch"]``.
 
 The BASELINE path — no deadline, no ``max_queue``, feasible,
 uncontended — is bit-identical to the PR-8 scheduler: same batch
@@ -123,12 +127,17 @@ from jax.sharding import PartitionSpec as P
 
 from ..core.stackelberg import (GameConfig, _oma_body, _random_body, _solve,
                                 stack_physics)
-from ..core.tracking import TRACE_COUNTS
+from ..core.tracking import TRACE_COUNTS, span
 from ..sharding import game_mesh
 
 DEFAULT_BUCKETS = (8, 16, 32, 64, 128)
 SERVE_SCHEMES = ("proposed", "ideal", "wo_dt", "oma", "oma_tdma", "random")
 STATUS_VOCAB = ("ok", "infeasible", "rejected", "shed", "timeout")
+# a batched row's wall time, submit to result, split where the work happens:
+# queued, packing the batch, the dispatch call, dispatched until its reap
+# starts, the reap's wait for the device, the readback and unpacking
+STAGES = ("queue_s", "pack_s", "launch_s", "inflight_s", "ready_wait_s",
+          "readback_s")
 
 
 # ---------------------------------------------------------------------------
@@ -251,7 +260,16 @@ class AllocResult:
     ``scheme`` is the scheme that produced the final arrays (``"oma"``
     after a fallback).  ``latency_s`` is always submit→emit wall time,
     including for rejected/shed rows (honest latency, ISSUE-9
-    satellite)."""
+    satellite).
+
+    ``stages`` splits ``latency_s`` of a row that went through a batch:
+    one entry per name in ``STAGES``, summing to ``latency_s``, plus
+    ``batch``, the dispatch's sequence number (shared by the rows of one
+    batch, and the ``batch=`` id of its ``serve.*`` spans).  A retried
+    row's ``queue_s`` runs from its first submit to the packing of the
+    batch that answered it; a batch whose dispatch call was retried
+    counts the failed attempts and their backoff in ``pack_s``.  None
+    for a rejected, shed or expired-in-queue row."""
     rid: int
     n: int
     bucket: int
@@ -271,6 +289,7 @@ class AllocResult:
     priority: int = 0
     deadline_s: float | None = None
     degradation: tuple = ()
+    stages: dict | None = None
 
 
 @dataclass
@@ -294,7 +313,10 @@ class _InFlight:
     key: tuple
     pending: list               # the real _Pending rows (dummies excluded)
     out: object                 # device Allocation, [B, nb] fields
-    t_dispatch: float
+    t_dispatch: float           # the dispatch call returned
+    batch: int                  # dispatch sequence number
+    t_pack: float               # packing started
+    t_launch: float             # the dispatch call started
 
 
 class _Breaker:
@@ -387,6 +409,9 @@ class AllocationService:
         self.breaker_log: list = []            # (key_str, old, new) capped
         self._lat: dict = collections.defaultdict(
             lambda: collections.deque(maxlen=self.latency_window))
+        self._stages = {s: collections.deque(maxlen=self.latency_window)
+                        for s in STAGES}
+        self._next_batch = 0
         self.stats = collections.Counter()
 
     # -- intake -------------------------------------------------------------
@@ -443,10 +468,15 @@ class AllocationService:
         self.stats[status] += 1
 
     def _predict_wait(self, key: tuple) -> float | None:
-        """Coarse queue-wait model for admission control: EWMA dispatch
-        seconds × (in-flight batches + this key's queued full batches +
-        the batch this request would join).  None (admit) until the
-        first measured completion seeds the EWMA."""
+        """Coarse queue-wait model for admission control: the EWMA of a
+        batch's seconds from the dispatch call's return to the end of its
+        ``block_until_ready`` at reap, × (in-flight batches + this key's
+        queued full batches + the batch this request would join).  That
+        time holds the device run and the wait until the batch is reaped
+        (the next ``drain``, a full in-flight window, a host stall), so
+        it errs high by that wait (``AllocResult.stages["inflight_s"]``
+        shows its size).  None (admit) until the first measured
+        completion seeds the EWMA."""
         ew = self._ewma.get(key)
         if ew is None:
             return None
@@ -463,6 +493,11 @@ class AllocationService:
         Fast-fail paths (all structured rows, never raises mid-stream):
         N exceeding the largest bucket, non-finite channel gains, an open
         circuit breaker, and admission control on ``deadline_s``."""
+        # every path below gives the request the rid that is next now
+        with span("serve.submit", rid=self._next_rid):
+            return self._submit(req)
+
+    def _submit(self, req: AllocRequest) -> int:
         t0 = time.perf_counter()
         if req.scheme not in SERVE_SCHEMES:
             raise ValueError(f"unknown scheme {req.scheme!r}; "
@@ -609,36 +644,44 @@ class AllocationService:
         dispatch failures with exponential backoff; a dispatch that
         still fails turns every request in the chunk into a structured
         ``"rejected"`` row and feeds the circuit breaker."""
+        t_pack = time.perf_counter()
+        batch = self._next_batch
+        self._next_batch += 1
         nb, scheme, inner, sic_mode = key
         b = self.batch_width                    # fixed batch width per
         n_real = len(rows)                      # executable (zero retraces)
-        h2 = np.zeros((b, nb), np.float32)
-        D = np.zeros((b, nb), np.float32)
-        vm = np.zeros((b, nb), np.float32)
-        mask = np.zeros((b, nb), bool)
-        eps = np.zeros((b,), np.float32)
-        for i, r in enumerate(rows):
-            h2[i, :r.n] = r.h2
-            D[i, :r.n] = r.d
-            vm[i, :r.n] = r.v_max
-            mask[i, :r.n] = True
-            eps[i] = r.req.epsilon
-        # dummy rows reuse the first request's physics (masked out anyway)
-        cfgs = [r.eff_cfg for r in rows] + [rows[0].eff_cfg] * (b - n_real)
-        phys = stack_physics(cfgs)
-        keys = jax.vmap(jax.random.PRNGKey)(jnp.asarray(
-            [r.req.seed for r in rows] + [0] * (b - n_real), jnp.uint32))
+        with span("serve.pack", batch=batch):
+            h2 = np.zeros((b, nb), np.float32)
+            D = np.zeros((b, nb), np.float32)
+            vm = np.zeros((b, nb), np.float32)
+            mask = np.zeros((b, nb), bool)
+            eps = np.zeros((b,), np.float32)
+            for i, r in enumerate(rows):
+                h2[i, :r.n] = r.h2
+                D[i, :r.n] = r.d
+                vm[i, :r.n] = r.v_max
+                mask[i, :r.n] = True
+                eps[i] = r.req.epsilon
+            # dummy rows reuse the first request's physics (masked out)
+            cfgs = ([r.eff_cfg for r in rows]
+                    + [rows[0].eff_cfg] * (b - n_real))
+            phys = stack_physics(cfgs)
+            keys = jax.vmap(jax.random.PRNGKey)(jnp.asarray(
+                [r.req.seed for r in rows] + [0] * (b - n_real),
+                jnp.uint32))
         last_err = None
         for attempt in range(self.dispatch_retries + 1):
             if attempt:
                 self.stats["dispatch_retries"] += 1
                 time.sleep(self.backoff_base_s * (2 ** (attempt - 1)))
             try:
-                out = self._dispatch(phys, keys, h2, D, vm, eps, mask,
-                                     jnp.asarray(self.tol, jnp.float32),
-                                     scheme=scheme, max_iter=self.max_iter,
-                                     inner=inner, sic_mode=sic_mode,
-                                     shards=self.shards)
+                t_launch = time.perf_counter()
+                with span("serve.launch", batch=batch):
+                    out = self._dispatch(
+                        phys, keys, h2, D, vm, eps, mask,
+                        jnp.asarray(self.tol, jnp.float32), scheme=scheme,
+                        max_iter=self.max_iter, inner=inner,
+                        sic_mode=sic_mode, shards=self.shards)
                 break
             except Exception as e:              # noqa: BLE001 — seam errors
                 last_err = e
@@ -653,8 +696,9 @@ class AllocationService:
                         f"{self.dispatch_retries + 1} attempts: "
                         f"{last_err}", bucket=nb)
             return
-        self._inflight.append(_InFlight(key=key, pending=rows, out=out,
-                                        t_dispatch=time.perf_counter()))
+        self._inflight.append(_InFlight(
+            key=key, pending=rows, out=out, t_dispatch=time.perf_counter(),
+            batch=batch, t_pack=t_pack, t_launch=t_launch))
         self.stats["dispatches"] += 1
         self.stats["padded_slots"] += b - n_real
 
@@ -738,6 +782,13 @@ class AllocationService:
 
     # -- completion ---------------------------------------------------------
     def _complete(self, inf: _InFlight) -> None:
+        """Reap one in-flight batch: wait for it, read it back, emit or
+        requeue its rows."""
+        t_reap = time.perf_counter()
+        with span("serve.reap", batch=inf.batch):
+            self._retire(inf, t_reap)
+
+    def _retire(self, inf: _InFlight, t_reap: float) -> None:
         key = inf.key
         nb = key[0]
         try:
@@ -751,11 +802,15 @@ class AllocationService:
                         r, "rejected", f"batch execution failed: {e}",
                         bucket=nb)
             return
-        dt = time.perf_counter() - inf.t_dispatch
+        t_ready = time.perf_counter()
+        dt = t_ready - inf.t_dispatch
         real = [i for i, r in enumerate(inf.pending) if r.rid >= 0]
         if real:
-            # EWMA of measured dispatch latency feeds admission control;
-            # warmup probes (compile-dominated, no real rows) don't seed it
+            # EWMA of dispatch-return -> ready-at-reap seconds feeds
+            # admission control: the device run plus the wait until this
+            # reap (the next drain, or a host stall), not the device run
+            # alone.  Warmup probes (compile-dominated, no real rows)
+            # don't seed it
             prev = self._ewma.get(key)
             self._ewma[key] = dt if prev is None else (
                 self.ewma_alpha * dt + (1.0 - self.ewma_alpha) * prev)
@@ -776,6 +831,11 @@ class AllocationService:
                           or (self.breaker_on_infeasible
                               and all_infeasible)))
         now = time.perf_counter()
+        batch_stages = {"pack_s": inf.t_launch - inf.t_pack,
+                        "launch_s": inf.t_dispatch - inf.t_launch,
+                        "inflight_s": t_reap - inf.t_dispatch,
+                        "ready_wait_s": t_ready - t_reap,
+                        "readback_s": now - t_ready}
         for i, r in enumerate(inf.pending):
             if r.rid < 0:              # warmup probe row — not a user request
                 continue
@@ -798,6 +858,8 @@ class AllocationService:
             inv[r.order] = np.arange(r.n)        # SIC order → request order
             unsort = lambda a: np.ascontiguousarray(a[i, :r.n][inv])
             latency = now - r.t_submit
+            stages = {"queue_s": inf.t_pack - r.t_submit, **batch_stages,
+                      "batch": inf.batch}
             late = (r.req.deadline_s is not None
                     and latency > r.req.deadline_s)
             if not feasible:
@@ -820,9 +882,12 @@ class AllocationService:
                 iterations=int(host["iterations"][i]),
                 latency_s=latency,
                 status=status, error=error, priority=r.req.priority,
-                deadline_s=r.req.deadline_s, degradation=r.degradation))
+                deadline_s=r.req.deadline_s, degradation=r.degradation,
+                stages=stages))
             self.stats["completed"] += 1
             self._lat[r.req.priority].append(latency)
+            for name in STAGES:
+                self._stages[name].append(stages[name])
             if not feasible:
                 self.stats["infeasible"] += 1
             elif late:
@@ -846,16 +911,19 @@ class AllocationService:
     # -- observability ------------------------------------------------------
     def health(self) -> dict:
         """Resilience snapshot: queue depths, breaker states, EWMA
-        dispatch latencies, every counter, and per-priority p50/p99
-        latency over the last ``latency_window`` completions."""
-        lat = {}
-        for pri in sorted(self._lat):
-            arr = np.asarray(self._lat[pri], np.float64) * 1e3
-            if arr.size:
-                lat[str(pri)] = {
-                    "n": int(arr.size),
+        dispatch latencies, every counter, per-priority p50/p99 latency
+        and p50/p99 of each of ``STAGES`` over the last
+        ``latency_window`` completions."""
+        def pct(window):
+            arr = np.asarray(window, np.float64) * 1e3
+            return {"n": int(arr.size),
                     "p50_ms": float(np.percentile(arr, 50)),
                     "p99_ms": float(np.percentile(arr, 99))}
+
+        lat = {str(pri): pct(self._lat[pri])
+               for pri in sorted(self._lat) if self._lat[pri]}
+        stages = {name: pct(window)
+                  for name, window in self._stages.items() if window}
         return {
             "queued": {self._key_str(k): len(v)
                        for k, v in self._pending.items() if v},
@@ -869,6 +937,7 @@ class AllocationService:
                                 for k, v in self._ewma.items()},
             "counters": {k: int(v) for k, v in sorted(self.stats.items())},
             "latency_by_priority_ms": lat,
+            "stages": stages,
         }
 
     # -- pre-compilation ----------------------------------------------------
