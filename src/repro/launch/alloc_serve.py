@@ -27,9 +27,12 @@ through a SMALL FIXED SET of bucket executables:
   * **double-buffered dispatch** — flushes enqueue asynchronously (JAX
     async dispatch keeps the device busy) and block only when more than
     ``max_inflight`` batches are outstanding, overlapping host-side
-    pack/unpack with device compute.  Operand buffers are donated to the
-    executable (the [B, nb] inputs are dead after dispatch and XLA may
-    reuse them for the outputs).
+    pack/unpack with device compute.  A batch goes to the device as two
+    host buffers (``pack_rows``: every float operand in one, the client
+    counts and seeds in the other) in one ``device_put``; the executable
+    slices them apart and builds the mask and PRNG keys itself.  The
+    readback of the fields a result needs starts as soon as the dispatch
+    call returns, so it lands while the host waits for the next reap.
 
 One executable exists per (scheme, bucket width, batch width,
 dinkelbach_inner, sic_mode); ``warmup()`` pre-compiles the set so a
@@ -116,17 +119,17 @@ import collections
 import dataclasses
 import time
 from dataclasses import dataclass, field
-from functools import partial
+from functools import lru_cache, partial
 from typing import Sequence
 
 import numpy as np
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import PartitionSpec as P
+from jax.sharding import NamedSharding, PartitionSpec as P
 
-from ..core.stackelberg import (GameConfig, _oma_body, _random_body, _solve,
-                                stack_physics)
+from ..core.stackelberg import (_PHYSICS_FIELDS, GameConfig, GamePhysics,
+                                _oma_body, _random_body, _solve)
 from ..core.tracking import TRACE_COUNTS, span
 from ..sharding import game_mesh
 
@@ -143,31 +146,104 @@ STAGES = ("queue_s", "pack_s", "launch_s", "inflight_s", "ready_wait_s",
 # ---------------------------------------------------------------------------
 # the bucket executable
 # ---------------------------------------------------------------------------
+# the fields of an Allocation that a result reads back
+READ_FIELDS = ("p", "q", "f", "alpha", "rates", "t_total", "energy",
+               "feasible", "iterations")
+_N_PHYS = len(_PHYSICS_FIELDS)
+
+
+@lru_cache(maxsize=4096)
+def _physics_row(cfg: GameConfig) -> np.ndarray:
+    """A config's GamePhysics floats in ``_PHYSICS_FIELDS`` order, float32
+    as ``stack_physics`` rounds them (read-only: it is shared)."""
+    row = np.asarray([getattr(cfg, name) for name in _PHYSICS_FIELDS],
+                     np.float32)
+    row.flags.writeable = False
+    return row
+
+
+def pack_rows(rows: Sequence, nb: int, b: int):
+    """Host buffers of one bucket dispatch of ``b`` rows × ``nb`` lanes:
+    float32 [b, 3·nb + 1 + 11] (h2, D, v_max, epsilon, the GamePhysics
+    floats) and uint32 [b, 2] (real-client count, request seed).  Each
+    row is a ``_Pending`` in SIC order; rows past ``len(rows)`` are
+    dummies (no clients) that reuse the first row's physics."""
+    fbuf = np.zeros((b, 3 * nb + 1 + _N_PHYS), np.float32)
+    ibuf = np.zeros((b, 2), np.uint32)
+    for i, r in enumerate(rows):
+        fbuf[i, :r.n] = r.h2
+        fbuf[i, nb:nb + r.n] = r.d
+        fbuf[i, 2 * nb:2 * nb + r.n] = r.v_max
+        fbuf[i, 3 * nb] = r.req.epsilon
+        fbuf[i, 3 * nb + 1:] = _physics_row(r.eff_cfg)
+        ibuf[i] = r.n, r.req.seed
+    fbuf[len(rows):, 3 * nb + 1:] = fbuf[0, 3 * nb + 1:]
+    return fbuf, ibuf
+
+
+def _start_readback(out) -> bool:
+    """Start the device→host copy of every field in ``READ_FIELDS``; False
+    where the output's arrays cannot copy ahead (the reap reads them
+    all the same)."""
+    try:
+        for f in READ_FIELDS:
+            getattr(out, f).copy_to_host_async()
+    except AttributeError:
+        return False
+    return True
+
+
+def unpack_rows(fbuf, ibuf):
+    """The executable's view of ``pack_rows``' buffers: (GamePhysics with
+    [B] leaves, [B, 2] PRNG keys, h2, D, v_max [B, nb], epsilon [B], mask
+    [B, nb] True on real client lanes)."""
+    nb = (fbuf.shape[-1] - 1 - _N_PHYS) // 3
+    phys = GamePhysics(**{name: fbuf[:, 3 * nb + 1 + j]
+                          for j, name in enumerate(_PHYSICS_FIELDS)})
+    keys = jax.vmap(jax.random.PRNGKey)(ibuf[:, 1])
+    mask = jnp.arange(nb, dtype=ibuf.dtype)[None, :] < ibuf[:, :1]
+    return (phys, keys, fbuf[:, :nb], fbuf[:, nb:2 * nb],
+            fbuf[:, 2 * nb:3 * nb], fbuf[:, 3 * nb], mask)
+
+
+def solve_row(scheme, max_iter, inner, sic_mode, ph, key, h2, D, v_max,
+              eps, mask, tol):
+    """One request's allocation in its bucket: the scheme's body on one
+    row of ``unpack_rows``' operands (h2 descending with a zero tail, D and
+    v_max zero on padded lanes)."""
+    dtype = jnp.result_type(h2)
+    if scheme in ("proposed", "ideal"):
+        return _solve(ph, h2, D, v_max, eps, max_iter, tol, inner, sic_mode,
+                      mask=mask)
+    if scheme == "wo_dt":
+        return _solve(ph, h2, D, jnp.zeros_like(h2), jnp.zeros((), dtype),
+                      max_iter, tol, inner, sic_mode, mask=mask)
+    if scheme == "oma":
+        return _oma_body(ph, h2, D, v_max, eps, inner, tdma=False, mask=mask)
+    if scheme == "oma_tdma":
+        return _oma_body(ph, h2, D, v_max, eps, inner, tdma=True, mask=mask)
+    if scheme == "random":
+        return _random_body(ph, key, h2, D, v_max, eps, mask=mask)
+    raise ValueError(f"unknown scheme {scheme!r}")
+
+
 @partial(jax.jit,
          static_argnames=("scheme", "max_iter", "inner", "sic_mode",
-                          "shards"),
-         donate_argnums=(2, 3, 4, 5))
-def _serve_batch_jit(phys, keys, h2, D, v_max, eps, mask, tol, scheme,
-                     max_iter, inner, sic_mode, shards=1):
+                          "shards"))
+def _serve_batch_jit(fbuf, ibuf, tol, scheme, max_iter, inner, sic_mode,
+                     shards=1):
     """One padded bucket dispatch: B requests × nb client lanes.
 
-    phys  : GamePhysics with [B] leaves (per-request physics knobs)
-    keys  : [B, 2] PRNG keys (consumed by the "random" scheme only)
-    h2    : [B, nb] channel gains, each row descending with a zero tail
-    D     : [B, nb] data sizes (zero on padded lanes)
-    v_max : [B, nb] insensitive fractions (zero on padded lanes)
-    eps   : [B] per-request DT deviation
-    mask  : [B, nb] bool, True on real client lanes
-    tol   : Alg.-2 stopping tolerance (scalar operand)
+    fbuf : [B, 3·nb + 12] float32 operands of ``pack_rows``
+    ibuf : [B, 2] uint32 real-client count and request seed
+    tol  : Alg.-2 stopping tolerance (scalar operand)
+
+    Returns an ``Allocation`` with [B, nb] / [B] fields, batch axis first.
 
     Static keys: scheme / max_iter / inner / sic_mode (+ the B, nb
     shapes).  Everything else — including every physics float — is a
     traced operand, so one executable serves arbitrarily heterogeneous
-    cells.  The [B, nb] operand buffers (h2, D, v_max) and eps are
-    donated — dead after dispatch, XLA reuses them for the matching
-    [B, nb] outputs (p/q/f/alpha/rates) and the [B] scalars.  The
-    GamePhysics leaves stay undonated: only two [B] f32 outputs exist
-    to absorb eleven [B] leaves, and XLA warns on every unusable one.
+    cells.  Nothing is donated: no output has a packed buffer's shape.
 
     ``shards`` > 1 splits the batch axis over the 1D draw mesh via
     ``shard_map`` (each device solves its local rows' independent
@@ -176,35 +252,17 @@ def _serve_batch_jit(phys, keys, h2, D, v_max, eps, mask, tol, scheme,
     """
     TRACE_COUNTS["serve_allocation"] += 1
 
-    def batch(ph_b, kk, h2_b, d_b, vm_b, eps_b, m_b, tl):
-        def one(ph, key, h2_r, d_r, vm_r, eps_r, m_r):
-            dtype = jnp.result_type(h2_r)
-            if scheme in ("proposed", "ideal"):
-                return _solve(ph, h2_r, d_r, vm_r, eps_r, max_iter, tl,
-                              inner, sic_mode, mask=m_r)
-            if scheme == "wo_dt":
-                return _solve(ph, h2_r, d_r, jnp.zeros_like(h2_r),
-                              jnp.zeros((), dtype), max_iter, tl, inner,
-                              sic_mode, mask=m_r)
-            if scheme == "oma":
-                return _oma_body(ph, h2_r, d_r, vm_r, eps_r, inner,
-                                 tdma=False, mask=m_r)
-            if scheme == "oma_tdma":
-                return _oma_body(ph, h2_r, d_r, vm_r, eps_r, inner,
-                                 tdma=True, mask=m_r)
-            if scheme == "random":
-                return _random_body(ph, key, h2_r, d_r, vm_r, eps_r,
-                                    mask=m_r)
-            raise ValueError(f"unknown scheme {scheme!r}")
-
-        return jax.vmap(one)(ph_b, kk, h2_b, d_b, vm_b, eps_b, m_b)
+    def batch(fb, ib, tl):
+        one = partial(solve_row, scheme, max_iter, inner, sic_mode)
+        return jax.vmap(one, in_axes=(0,) * 7 + (None,))(
+            *unpack_rows(fb, ib), tl)
 
     if shards > 1:
         d = P(game_mesh.DRAW_AXIS)
         batch = jax.shard_map(batch, mesh=game_mesh.mesh_1d(shards),
-                              in_specs=(d,) * 7 + (P(),), out_specs=d,
+                              in_specs=(d, d, P()), out_specs=d,
                               check_vma=False)
-    return batch(phys, keys, h2, D, v_max, eps, mask, tol)
+    return batch(fbuf, ibuf, tol)
 
 
 # ---------------------------------------------------------------------------
@@ -381,6 +439,15 @@ class AllocationService:
         self.max_inflight = int(max_inflight)
         self.max_iter = int(max_iter)
         self.tol = float(tol)
+        # where a dispatch's packed buffers and tol live: split on the batch
+        # axis, and replicated, over the mesh the executable runs on
+        rows_at = tol_at = None
+        if self.shards > 1:
+            mesh = game_mesh.mesh_1d(self.shards)
+            rows_at = NamedSharding(mesh, P(game_mesh.DRAW_AXIS))
+            tol_at = NamedSharding(mesh, P())
+        self._rows_at = rows_at
+        self._tol = jax.device_put(np.float32(self.tol), tol_at)
         self.max_queue = None if max_queue is None else int(max_queue)
         self.ewma_alpha = float(ewma_alpha)
         self.degraded_retry = bool(degraded_retry)
@@ -651,24 +718,8 @@ class AllocationService:
         b = self.batch_width                    # fixed batch width per
         n_real = len(rows)                      # executable (zero retraces)
         with span("serve.pack", batch=batch):
-            h2 = np.zeros((b, nb), np.float32)
-            D = np.zeros((b, nb), np.float32)
-            vm = np.zeros((b, nb), np.float32)
-            mask = np.zeros((b, nb), bool)
-            eps = np.zeros((b,), np.float32)
-            for i, r in enumerate(rows):
-                h2[i, :r.n] = r.h2
-                D[i, :r.n] = r.d
-                vm[i, :r.n] = r.v_max
-                mask[i, :r.n] = True
-                eps[i] = r.req.epsilon
-            # dummy rows reuse the first request's physics (masked out)
-            cfgs = ([r.eff_cfg for r in rows]
-                    + [rows[0].eff_cfg] * (b - n_real))
-            phys = stack_physics(cfgs)
-            keys = jax.vmap(jax.random.PRNGKey)(jnp.asarray(
-                [r.req.seed for r in rows] + [0] * (b - n_real),
-                jnp.uint32))
+            bufs = pack_rows(rows, nb, b)
+            operands = jax.device_put(bufs, self._rows_at)
         last_err = None
         for attempt in range(self.dispatch_retries + 1):
             if attempt:
@@ -678,10 +729,11 @@ class AllocationService:
                 t_launch = time.perf_counter()
                 with span("serve.launch", batch=batch):
                     out = self._dispatch(
-                        phys, keys, h2, D, vm, eps, mask,
-                        jnp.asarray(self.tol, jnp.float32), scheme=scheme,
+                        *operands, self._tol, scheme=scheme,
                         max_iter=self.max_iter, inner=inner,
                         sic_mode=sic_mode, shards=self.shards)
+                    # on what the seam returned, after any wrapper ran
+                    prefetched = _start_readback(out)
                 break
             except Exception as e:              # noqa: BLE001 — seam errors
                 last_err = e
@@ -700,6 +752,8 @@ class AllocationService:
             key=key, pending=rows, out=out, t_dispatch=time.perf_counter(),
             batch=batch, t_pack=t_pack, t_launch=t_launch))
         self.stats["dispatches"] += 1
+        self.stats["operand_buffers"] += len(bufs)
+        self.stats["readback_prefetched"] += prefetched
         self.stats["padded_slots"] += b - n_real
 
     def _flush_key(self, key: tuple) -> None:
@@ -818,9 +872,7 @@ class AllocationService:
                          and dt > self.watchdog_s)
         if watchdog_trip:
             self.stats["watchdog_trips"] += 1
-        host = {f: np.asarray(getattr(out, f))
-                for f in ("p", "q", "f", "alpha", "rates", "t_total",
-                          "energy", "feasible", "iterations")}
+        host = jax.device_get({f: getattr(out, f) for f in READ_FIELDS})
         if real:
             idx = np.asarray(real)
             finite = all(np.all(np.isfinite(host[f][idx]))
@@ -924,6 +976,7 @@ class AllocationService:
                for pri in sorted(self._lat) if self._lat[pri]}
         stages = {name: pct(window)
                   for name, window in self._stages.items() if window}
+        d = max(self.stats["dispatches"], 1)
         return {
             "queued": {self._key_str(k): len(v)
                        for k, v in self._pending.items() if v},
@@ -936,6 +989,10 @@ class AllocationService:
             "ewma_dispatch_s": {self._key_str(k): round(v, 6)
                                 for k, v in self._ewma.items()},
             "counters": {k: int(v) for k, v in sorted(self.stats.items())},
+            # host buffers sent, and readbacks started at dispatch, per
+            # dispatch (2.0 and 1.0 on the packed path)
+            "per_dispatch": {k: self.stats[k] / d for k in
+                             ("operand_buffers", "readback_prefetched")},
             "latency_by_priority_ms": lat,
             "stages": stages,
         }

@@ -7,16 +7,21 @@ zero-gain tails are invisible to every suffix sum and the mask erases the
 padded lanes from every reduction) — and a mixed-N stream over warm buckets
 must trigger ZERO retraces (TRACE_COUNTS["serve_allocation"]).
 """
+import dataclasses
+from functools import partial
+
 import numpy as np
 import pytest
 
+import jax
 import jax.numpy as jnp
 
 from repro.core.fl_round import allocate_batched
-from repro.core.stackelberg import GameConfig
+from repro.core.stackelberg import GameConfig, stack_physics
 from repro.core.tracking import TRACE_COUNTS
-from repro.launch.alloc_serve import (DEFAULT_BUCKETS, AllocationService,
-                                      AllocRequest)
+from repro.launch.alloc_serve import (DEFAULT_BUCKETS, READ_FIELDS,
+                                      SERVE_SCHEMES, AllocationService,
+                                      AllocRequest, solve_row, unpack_rows)
 
 REL = 1e-5
 D_BITS, V_MAX, EPS = 200.0, 0.5, 0.05
@@ -237,3 +242,165 @@ class TestGracefulDegradation:
         assert "deadline" in res[r_bad].error
         assert svc.stats["infeasible"] == 1
         assert res[r_ok].status == "ok" and res[r_ok].feasible
+
+
+# ---------------------------------------------------------------------------
+# packed operands: two host buffers per dispatch, readback started at dispatch
+# ---------------------------------------------------------------------------
+def _mixed_batch(nb):
+    """Three requests in one bucket (a partial batch of four: one dummy
+    row), each with its own client count, t_max, epsilon and seed; one
+    also with its own bandwidth."""
+    ns = (3, 5, nb) if nb == 8 else (70, nb, 100)
+    cfgs = (GameConfig(t_max=0.8), GameConfig(t_max=10.0, bandwidth=2e6),
+            GameConfig(t_max=35.0))
+    seeds = (7, 2**31 + 3, 0)
+    rng = np.random.default_rng(nb)
+    return [AllocRequest(h2=rng.uniform(0.2, 2.0, n).astype(np.float32),
+                         d=rng.uniform(100.0, 300.0, n).astype(np.float32),
+                         v_max=0.5, cfg=cfg, epsilon=0.01 * (i + 1),
+                         seed=seed)
+            for i, (n, cfg, seed) in enumerate(zip(ns, cfgs, seeds))]
+
+
+def _parent_operands(reqs, nb, b):
+    """The operand layout each dispatch sent before packing: per-field
+    [b, nb] arrays in SIC order, ``stack_physics`` of the configs (dummy
+    rows take the first's), an eager mask and eager ``vmap(PRNGKey)``
+    keys."""
+    h2, D, vm = (np.zeros((b, nb), np.float32) for _ in range(3))
+    mask = np.zeros((b, nb), bool)
+    eps = np.zeros((b,), np.float32)
+    for i, q in enumerate(reqs):
+        x = np.asarray(q.h2, np.float32)
+        n = x.size
+        order = np.argsort(-x, kind="stable")
+        h2[i, :n] = x[order]
+        D[i, :n] = np.broadcast_to(np.asarray(q.d, np.float32), (n,))[order]
+        vm[i, :n] = np.broadcast_to(np.asarray(q.v_max, np.float32),
+                                    (n,))[order]
+        mask[i, :n] = True
+        eps[i] = q.epsilon
+    cfgs = [q.cfg for q in reqs] + [reqs[0].cfg] * (b - len(reqs))
+    keys = jax.vmap(jax.random.PRNGKey)(jnp.asarray(
+        [q.seed for q in reqs] + [0] * (b - len(reqs)), jnp.uint32))
+    return (stack_physics(cfgs), keys, jnp.asarray(h2), jnp.asarray(D),
+            jnp.asarray(vm), jnp.asarray(eps), jnp.asarray(mask))
+
+
+def _recorded_dispatch(reqs, nb, scheme):
+    """Run ``reqs`` through one partial-batch dispatch of a bucket-``nb``
+    service (no retry ladder, so it is the only one); returns (service,
+    the seam's positional operands, its output)."""
+    svc = AllocationService(buckets=(nb,), max_batch=4, degraded_retry=False)
+    real, seen = svc._dispatch, {}
+
+    def record(*a, **kw):
+        seen["args"], seen["out"] = a, real(*a, **kw)
+        return seen["out"]
+
+    svc._dispatch = record
+    for q in reqs:
+        svc.submit(dataclasses.replace(q, scheme=scheme))
+    svc.drain()
+    assert svc.stats["dispatches"] == 1
+    return svc, seen["args"], seen["out"]
+
+
+class TestPackedDispatch:
+    """The two packed buffers carry exactly the operands the per-field
+    layout sent, the answers are the same bits, and the readback starts
+    on whatever the dispatch seam returned."""
+
+    @pytest.mark.parametrize("nb", [8, 128])
+    def test_unpacked_operands_equal_parent_layout(self, nb):
+        """Every operand the executable unpacks — the physics leaves, the
+        PRNG keys made in the program, the mask from the client counts —
+        equals the per-field layout's, bit for bit."""
+        reqs = _mixed_batch(nb)
+        svc, args, _ = _recorded_dispatch(reqs, nb, "random")
+        got = jax.jit(unpack_rows)(*args[:2])
+        want = _parent_operands(reqs, nb, svc.batch_width)
+        for g, w in zip(jax.tree_util.tree_leaves(got),
+                        jax.tree_util.tree_leaves(want)):
+            assert g.dtype == w.dtype and g.shape == w.shape
+            np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+
+    @pytest.mark.parametrize("nb", [8, 128])
+    @pytest.mark.parametrize("scheme", SERVE_SCHEMES)
+    def test_dispatch_equals_parent_layout(self, scheme, nb):
+        """All nine fields a result reads, on every row (the dummy too),
+        equal a vmapped call of the same scheme body on the per-field
+        layout, bit for bit on the CPU."""
+        reqs = _mixed_batch(nb)
+        svc, _, out = _recorded_dispatch(reqs, nb, scheme)
+        cfg = reqs[0].cfg
+        body = partial(solve_row, scheme, svc.max_iter, cfg.dinkelbach_inner,
+                       cfg.sic_mode)
+        ref = jax.jit(jax.vmap(body, in_axes=(0,) * 7 + (None,)))(
+            *_parent_operands(reqs, nb, svc.batch_width),
+            jnp.asarray(svc.tol, jnp.float32))
+        for f in READ_FIELDS:
+            np.testing.assert_array_equal(np.asarray(getattr(out, f)),
+                                          np.asarray(getattr(ref, f)),
+                                          err_msg=f)
+
+    def test_warm_dispatch_sends_two_buffers_explicitly(self, monkeypatch):
+        """A warm service packs, dispatches and reaps a full batch with no
+        implicit host→device transfer, in one ``device_put`` of two
+        buffers, and starts every readback at dispatch."""
+        svc = AllocationService(buckets=(8,), max_batch=4)
+        svc.warmup()
+        reqs = [AllocRequest(h2=_draw(n, seed=50 + n), epsilon=EPS)
+                for n in (2, 3, 5, 8)]
+        puts = []
+        real_put = jax.device_put
+
+        def counting_put(x, *a, **kw):
+            puts.append(len(jax.tree_util.tree_leaves(x)))
+            return real_put(x, *a, **kw)
+
+        monkeypatch.setattr(jax, "device_put", counting_put)
+        with jax.transfer_guard("disallow"):
+            for q in reqs:
+                svc.submit(q)
+            res = svc.drain()
+        assert [r.status for r in res] == ["ok"] * 4
+        d = svc.stats["dispatches"]
+        assert d >= 2                              # warmup's and this one
+        assert puts == [2]
+        assert svc.stats["operand_buffers"] == 2 * d
+        assert svc.stats["readback_prefetched"] == d
+        assert svc.health()["per_dispatch"] == {"operand_buffers": 2.0,
+                                                "readback_prefetched": 1.0}
+
+    def test_readback_reads_what_the_seam_returned(self):
+        """A wrapper at the dispatch seam that alters ``p`` after the call
+        changes the result's ``p``: the readback started at dispatch is of
+        the wrapper's output.  A NaN-poisoned output is still rejected."""
+        h2 = _draw(5, seed=3)
+        base = _serve_one(h2, "proposed", GameConfig())
+        svc = AllocationService(buckets=(8, 16), max_batch=2)
+        real = svc._dispatch
+
+        def altered(*a, **kw):
+            out = real(*a, **kw)
+            return out.__class__(**{**vars(out), "p": out.p * 2.0})
+
+        svc._dispatch = altered
+        svc.submit(AllocRequest(h2=h2, d=D_BITS, v_max=V_MAX, epsilon=EPS))
+        (res,) = svc.drain()
+        np.testing.assert_array_equal(res.p, base.p * np.float32(2.0))
+        assert svc.stats["readback_prefetched"] == 1
+
+        def poisoned(*a, **kw):
+            return jax.tree_util.tree_map(
+                lambda x: jnp.full_like(x, jnp.nan)
+                if jnp.issubdtype(x.dtype, jnp.floating) else x,
+                real(*a, **kw))
+
+        svc._dispatch = poisoned
+        svc.submit(AllocRequest(h2=h2, d=D_BITS, v_max=V_MAX, epsilon=EPS))
+        (res,) = svc.drain()
+        assert res.status == "rejected"
+        assert "non-finite allocation" in res.error
