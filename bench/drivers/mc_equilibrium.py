@@ -113,3 +113,23 @@ class Cell:
             numbers.append(compare.allocation_numbers(got, ref,
                                                       self.phys["t_max"]))
         return compare.merge(numbers)
+
+
+def compiled_text(config: dict, traffic: dict) -> str:
+    """Compiled text of the program the cell's calls run:
+    ``batched_equilibrium`` at the cell's draws per call, clients and
+    solver settings."""
+    from repro.core import stackelberg as st
+    solver = config["solver"]
+    cfg = st.GameConfig(**inputs.physics(config),
+                        dinkelbach_inner=solver["dinkelbach_inner"],
+                        sic_mode=solver["sic_mode"])
+    zeros = np.zeros((int(traffic["draws_per_call"]),
+                      int(config["clients_per_round"])), np.float32)
+    phys, h2, d, vm, eps, tol, shards, _ = st._canon_batch(
+        cfg, zeros, zeros, zeros, float(traffic["epsilon"]),
+        float(solver["tol"]))
+    return st._batched_equilibrium_jit.lower(
+        phys, h2, d, vm, eps, tol, max_iter=int(solver["max_iter"]),
+        inner=cfg.dinkelbach_inner, sic_mode=cfg.sic_mode,
+        shards=shards).compile().as_text()

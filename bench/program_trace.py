@@ -171,41 +171,24 @@ def scope_share(trace, scopes: dict, scope: str) -> float | None:
     return 100.0 * held / busy
 
 
-def equilibrium_hlo(config: dict, traffic: dict) -> str:
-    """Compiled text of the program a closed-loop equilibrium cell runs
-    (``bench/drivers/mc_equilibrium.py``): ``batched_equilibrium`` at the
-    cell's draws per call, clients and solver settings."""
-    from bench import inputs
-    from repro.core import stackelberg as st
-    solver = config["solver"]
-    cfg = st.GameConfig(**inputs.physics(config),
-                        dinkelbach_inner=solver["dinkelbach_inner"],
-                        sic_mode=solver["sic_mode"])
-    zeros = np.zeros((int(traffic["draws_per_call"]),
-                      int(config["clients_per_round"])), np.float32)
-    phys, h2, d, vm, eps, tol, shards, _ = st._canon_batch(
-        cfg, zeros, zeros, zeros, float(traffic["epsilon"]),
-        float(solver["tol"]))
-    return st._batched_equilibrium_jit.lower(
-        phys, h2, d, vm, eps, tol, max_iter=int(solver["max_iter"]),
-        inner=cfg.dinkelbach_inner, sic_mode=cfg.sic_mode,
-        shards=shards).compile().as_text()
-
-
 def metric_scopes(metric: str, trace, root: Path = ROOT) -> dict:
-    """The op -> scope map of the program that ran, among the equilibrium
-    cells ``metric`` lists in ``BENCHMARK.json``: the one whose instruction
-    names cover most of the traced ops."""
-    from bench.run import resolve
+    """The op -> scope map of the program that ran, among the cells that
+    ``metric`` lists in ``BENCHMARK.json`` whose driver gives the compiled
+    text of its program (``compiled_text(config, traffic)``): the one whose
+    instruction names cover most of the traced ops."""
+    from bench.run import load_module, resolve
     bench = json.loads((root / "BENCHMARK.json").read_text())
     entry = next(m for m in bench["per_layer"] if m["name"] == metric)
     traced = {op_key(op) for c in trace.chips.values() for op, *_ in c.ops}
     best, cover = {}, -1
     for name in entry.get("workloads", []):
         spec = resolve(name, root)
-        if spec["traffic"]["driver"] != "mc_equilibrium":
+        driver = load_module(spec["driver"],
+                             f"bench_scopes_{spec['traffic']['driver']}")
+        if not hasattr(driver, "compiled_text"):
             continue
-        scopes = scope_map(equilibrium_hlo(spec["config"], spec["traffic"]))
+        scopes = scope_map(driver.compiled_text(spec["config"],
+                                                spec["traffic"]))
         hits = len(traced & scopes.keys())
         if hits > cover:
             best, cover = scopes, hits

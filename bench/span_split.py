@@ -72,9 +72,9 @@ def main(argv=None) -> int:
     if traffic["driver"] == "open_loop_service":
         out["serve_stages"] = program_trace.serve_stages(
             [res for _, res in cell.answered])
-    if traffic["driver"] == "mc_equilibrium":
+    if hasattr(driver, "compiled_text"):
         scopes = program_trace.scope_map(
-            program_trace.equilibrium_hlo(spec["config"], traffic))
+            driver.compiled_text(spec["config"], traffic))
         out["sic_power_device_share"] = program_trace.scope_share(
             trace, scopes, "sic_power")
     print(json.dumps(out), flush=True)

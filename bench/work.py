@@ -37,3 +37,23 @@ def roofline_share(work: dict, seconds: float, device_kind: str) -> float:
     least = max(work["flops"] / peak["flops_per_s"],
                 work["bytes"] / peak["hbm_bytes_per_s"])
     return 100.0 * least / seconds
+
+
+def mlp_weights(dims) -> int:
+    """Weights of the dense layers ``[(fan_in, fan_out), ...]``; biases
+    are left out: no matmul touches them."""
+    return int(sum(a * b for a, b in dims))
+
+
+def fl_training(weights: int, local_samples: float, mapped_samples: float,
+                local_steps: int, server_steps: int,
+                val_samples: float) -> dict:
+    """Model FLOPs of FL rounds on a dense classifier of ``weights``
+    weights: 6 per weight per weighted sample per full-batch SGD step (2 in
+    the forward pass, 4 in the backward), on the clients' unmapped samples
+    (``local_steps`` steps) and the twin's mapped ones (``server_steps``),
+    and 2 per weight per sample of each validation forward pass
+    (``val_samples`` = validation set x passes).  Zero-weighted slots
+    (padding, the other side of the DT split) are not work."""
+    sgd = local_steps * local_samples + server_steps * mapped_samples
+    return {"flops": float(6 * weights * sgd + 2 * weights * val_samples)}

@@ -3,12 +3,14 @@ plain reference in bfloat16 put in the program's place) at small sizes, and
 a whole run, past the look for a chip, with the timed path broken
 underneath."""
 import json
+import time
 
 import ml_dtypes
 import numpy as np
 import pytest
 
 from bench import compare, run
+from fl_small import CONFIG as SMALL_CONFIG, small
 
 ROOT = run.ROOT
 
@@ -23,6 +25,7 @@ CASES = {
     "paper_mc_n5": dict(traffic={"draws_per_call": 64, "pool": 2,
                                  "check_slots": 2}, config={}),
     "paper_serve_n5": dict(traffic={"rate_per_s": 200.0}, config={}),
+    "paper_fl_sweep_4chip": dict(traffic=small()[1], config=SMALL_CONFIG),
 }
 
 
@@ -105,18 +108,38 @@ def test_altered_answer_is_caught_service(monkeypatch, capsys, how):
     assert _main(monkeypatch, capsys, "paper_serve_n5")["correct"] is False
 
 
-def test_expired_requests_are_not_compared_service(monkeypatch, capsys):
-    """At a 20 ms deadline requests expire in the queue between ticks (a
-    ``timeout`` row with NaN arrays, never solved) or are solved late (a
-    ``timeout`` row with an allocation): the run stays correct, and the
-    expired ones count as failed."""
-    real_resolve = run.resolve
+def _stall_first_dispatch(monkeypatch, seconds):
+    """The service's dispatch seam sleeps ``seconds`` before the window's
+    first dispatch: the requests of that batch are answered late."""
+    real_load = run.load_module
 
-    def tight(workload, root=run.ROOT):
-        spec = real_resolve(workload, root)
-        spec["traffic"] = dict(spec["traffic"], deadline_s=0.02)
-        return spec
-    monkeypatch.setattr(run, "resolve", tight)
+    def load(path, name):
+        mod = real_load(path, name)
+        if name.startswith("bench_driver_"):
+            real_run = mod.Cell.run
+
+            def stalled_run(self, window):
+                seam, done = self.svc._dispatch, []
+
+                def stall_once(*args, **kw):
+                    if not done:
+                        done.append(True)
+                        time.sleep(seconds)
+                    return seam(*args, **kw)
+                self.svc._dispatch = stall_once
+                return real_run(self, window)
+            monkeypatch.setattr(mod.Cell, "run", stalled_run)
+        return mod
+    monkeypatch.setattr(run, "load_module", load)
+
+
+def test_expired_requests_are_not_compared_service(monkeypatch, capsys):
+    """One dispatch of the window stalls past the 1 s deadline, so the
+    requests of that batch are solved late: ``timeout`` rows that still
+    carry an allocation (a row that expired in the queue carries NaN
+    arrays and is not compared).  The run stays correct, and the late ones
+    count as failed."""
+    _stall_first_dispatch(monkeypatch, 1.1)
     out = _main(monkeypatch, capsys, "paper_serve_n5")
     assert out["counters"]["statuses"]["timeout"] > 0, out["counters"]
     assert out["failed"] > 0

@@ -137,8 +137,9 @@ def test_sic_power_reader_reads_the_cells_program():
     (here compiled for the CPU), the share is the time of the ops under
     ``sic_power`` over the busy time."""
     spec = run.resolve("paper_mc_n5", ROOT)
+    driver = run.load_module(spec["driver"], "mc_driver_scopes")
     scopes = program_trace.scope_map(
-        program_trace.equilibrium_hlo(spec["config"], spec["traffic"]))
+        driver.compiled_text(spec["config"], spec["traffic"]))
     inside = next(n for n, s in scopes.items() if "/sic_power/" in s)
     outside = next(n for n, s in scopes.items() if s and "sic_power" not in s)
     chip = trace_reduce.Chip(
