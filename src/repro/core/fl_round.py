@@ -7,7 +7,9 @@ Round pipeline (§II–§V):
   4. DT data split: Bernoulli(v_n) per sample → server-mapped (with ε
      feature deviation) vs local                             (§II)
   5. local SGD on clients (poisoners train on flipped labels) (Eq. 2)
-     + server/DT SGD on the union of mapped data
+     + server/DT SGD on the union of mapped data; a client model too
+     large for a copy per client is trained one client at a time, each
+     judged (7) and summed into Eq. (3) (8) as it is done
   6. deadline check: clients with t_cmp + t_com > T_max straggle and
      drop out (the mechanism DT/NOMA alleviate)
   7. RONI validation → PI/NI bookkeeping, exclusion          (§III-3)
@@ -63,12 +65,12 @@ from jax.sharding import PartitionSpec as P
 from ..data.federated import FedData
 from ..sharding import game_mesh
 from . import reputation as rep
-from .aggregation import dt_aggregate, fedavg
+from .aggregation import dt_aggregate, eq3_weights, fedavg, fedavg_weights
 from .digital_twin import dt_feature_noise, split_mapping_mask
 from .faults import (FaultConfig, FaultOps, attack_active, faded_channel,
                      fault_ops, sample_round_faults, slowdown_multiplier,
                      stack_fault_ops)
-from .roni import roni_filter
+from .roni import roni_filter, roni_verdict
 from .stackelberg import (TRACE_COUNTS, Allocation, GameConfig,
                           _oma_body, _physics_cached, _random_body,
                           _shard_axis, _solve, batched_equilibrium,
@@ -266,6 +268,185 @@ def _allocate_traced(scheme: str, phys, inner: str, key, h2_sorted, d_units,
     raise ValueError(scheme)
 
 
+def _scans_clients(model) -> bool:
+    """Whether the round trains ``model`` one client at a time: a client
+    model object that says so (``scan_clients``, e.g.
+    ``models.nemotron_h.LMClient``); a classifier's logits function is
+    trained for every selected client at once."""
+    return bool(getattr(model, "scan_clients", False))
+
+
+def _train_clients_vmapped(logits_fn, params, data, xs, ys_train, local_w,
+                           map_mask, meets, d_units, v, ops, k_dt,
+                           scheme: str, use_roni: bool, local_steps: int,
+                           server_steps: int):
+    """Stages 5, 7 and 8 for a classifier: full-batch local SGD of every
+    selected client at once (``local_train_all``), the twin's SGD on the
+    mapped samples, RONI, Eq. (3).  Returns (new params, positive [N],
+    no extra metrics)."""
+    n_selected = xs.shape[0]
+    # 5a. local SGD (poisoners flip labels locally)
+    with jax.named_scope("fl.local_sgd"):
+        client_params = local_train_all(logits_fn, params, xs, ys_train,
+                                        local_w, local_steps, ops["lr"])
+    # 5b. server/DT SGD on mapped data (ε feature deviation).  The twin
+    # mirrors the client's data AS-IS — a poisoner's mapped samples carry
+    # the flipped labels too (the twin holds no clean copy to check them by)
+    n, cap, dim = xs.shape
+    with jax.named_scope("fl.twin_sgd"):
+        x_dt = dt_feature_noise(k_dt, xs, ops["epsilon"]).reshape(n * cap,
+                                                                  dim)
+        server_params = sgd_train(logits_fn, params, x_dt,
+                                  ys_train.reshape(-1),
+                                  map_mask.reshape(-1).astype(jnp.float32),
+                                  server_steps, ops["lr"])
+
+    # 7. RONI
+    with jax.named_scope("fl.roni"):
+        if use_roni:
+            # per-update RONI against the pre-round global model (Biscotti
+            # [31]); the DT/server update is validated the same way — the
+            # twin mirrors poisoned mapped data too
+            positive, acc_base, _ = roni_filter(client_params, params,
+                                                d_units, v, ops["epsilon"],
+                                                logits_fn, data.x_val,
+                                                data.y_val,
+                                                ops["roni_threshold"])
+            server_ok = roni_verdict(
+                acc_base[0], _val_acc(logits_fn, data.x_val, data.y_val,
+                                      server_params), ops["roni_threshold"])
+        else:
+            positive = jnp.ones((n_selected,), bool)
+            server_ok = jnp.asarray(True)
+    include = positive & meets
+
+    # 8. aggregation (Eq. 3); ideal uses plain FedAvg on full local data.
+    # If RONI rejected EVERYTHING this round, keep the previous global model
+    # (an empty aggregate would zero the parameters) — a jnp.where over the
+    # parameter pytree, so the decision stays on-device inside the scan.
+    with jax.named_scope("fl.aggregate"):
+        if scheme == "ideal":
+            agg = fedavg(client_params, d_units, include_mask=include)
+            any_included = jnp.any(include)
+        else:
+            agg = dt_aggregate(client_params, server_params, d_units, v,
+                               ops["epsilon"], include_mask=include,
+                               server_include=server_ok)
+            any_included = jnp.any(include) | server_ok
+        new_params = jax.tree_util.tree_map(
+            lambda new, old: jnp.where(any_included, new, old),
+            agg, params)
+    return new_params, positive, {}
+
+
+def minibatch_sgd(model, params, x, y, w, keys, lr, deviate=None):
+    """SGD on ``model.loss`` with one minibatch of ``model.batch`` sequences
+    a step, one step per key of ``keys``.  Each draw picks, among the rows
+    of weight > 0, the one with the largest U[0, 1) of the step's key
+    (``uniform(key, (batch, rows))``): uniform over those rows, with
+    replacement; where none has weight the step draws weight 0 and does
+    nothing.  ``deviate`` = (key, ε) puts the twin's deviation on the drawn
+    rows' inputs, from ``fold_in(key, row)``, so that a row reads the same
+    each time it is drawn.  Returns (params, the loss's aux stacked over the
+    steps)."""
+    rows = w.shape[0]
+
+    def step(p, key):
+        u = jax.random.uniform(key, (model.batch, rows))
+        pick = jnp.argmax(jnp.where(w > 0, u, -1.0), axis=1)
+        xb = x[pick]
+        if deviate is not None:
+            k_dev, eps = deviate
+            xb = jax.vmap(lambda j, xr: model.deviate(
+                jax.random.fold_in(k_dev, j), xr, eps))(pick, xb)
+        (_, aux), g = jax.value_and_grad(model.loss, has_aux=True)(
+            p, xb, y[pick], w[pick])
+        return jax.tree_util.tree_map(lambda a, b: a - lr * b, p, g), aux
+
+    return jax.lax.scan(step, params, keys)
+
+
+def _train_clients_scanned(model, params, data, xs, ys_train, local_w,
+                           map_mask, meets, d_units, v, ops, k_dt,
+                           scheme: str, use_roni: bool, local_steps: int,
+                           server_steps: int):
+    """Stages 5, 7 and 8 for a model trained one client at a time: the
+    twin's minibatch SGD on every mapped sequence, then a scan over the N
+    selected clients, each trained (minibatch SGD on its unmapped
+    sequences), judged by RONI against the pre-round global model and added
+    to a running Eq. (3) sum, which is divided once at the end.  At most
+    one client's parameters and the running sum live at a time.
+
+    Keys: ``k_dev, k_local, k_twin = split(k_dt, 3)``; client i's step j
+    draws from ``split(k_local, (N, local_steps))[i, j]``, the twin's step
+    j from ``split(k_twin, server_steps)[j]``, and the twin's deviation of
+    flat row r (client-major, N·cap rows) from ``fold_in(k_dev, r)``.
+    Returns (new params, positive [N], the loss's aux of every step,
+    stacked twin first over the N + 1 trainings: [N + 1, steps, ...])."""
+    n, cap = local_w.shape
+    k_dev, k_local, k_twin = jax.random.split(k_dt, 3)
+    thr = ops["roni_threshold"]
+    acc = lambda p: model.accuracy(p, data.x_val, data.y_val)
+    with jax.named_scope("fl.roni"):
+        acc_base = acc(params)
+    flat = lambda a: a.reshape((n * cap,) + a.shape[2:])
+    with jax.named_scope("fl.twin_sgd"):
+        twin, twin_aux = minibatch_sgd(
+            model, params, flat(xs), flat(ys_train),
+            flat(map_mask).astype(jnp.float32),
+            jax.random.split(k_twin, server_steps), ops["lr"],
+            deviate=(k_dev, ops["epsilon"]))
+    with jax.named_scope("fl.roni"):
+        server_ok = roni_verdict(acc_base, acc(twin), thr) if use_roni \
+            else jnp.asarray(True)
+    # each client's weight and the twin's (zero in plain FedAvg, a rejected
+    # twin's too); a client's is dropped in the scan if RONI or the
+    # deadline excludes it, as ``include_mask`` drops it in Eq. (3)
+    if scheme == "ideal":
+        a, _ = fedavg_weights(d_units)
+        w_twin = jnp.zeros_like(jnp.sum(d_units))
+    else:
+        a, w_twin, _ = eq3_weights(d_units, v, ops["epsilon"],
+                                   server_include=server_ok)
+    with jax.named_scope("fl.aggregate"):
+        total = jax.tree_util.tree_map(lambda q: w_twin * q, twin)
+
+    def client(total, inp):
+        x_i, y_i, w_i, keys_i, a_i, meets_i = inp
+        with jax.named_scope("fl.local_sgd"):
+            p_i, aux = minibatch_sgd(model, params, x_i, y_i, w_i, keys_i,
+                                     ops["lr"])
+        with jax.named_scope("fl.roni"):
+            ok = roni_verdict(acc_base, acc(p_i), thr) if use_roni else \
+                jnp.asarray(True)
+        with jax.named_scope("fl.aggregate"):
+            c = jnp.where(ok & meets_i, a_i, 0.0)
+            total = jax.tree_util.tree_map(lambda t, q: t + c * q, total,
+                                           p_i)
+        return total, (ok, aux)
+
+    local_keys = jax.random.split(k_local, n * local_steps).reshape(
+        (n, local_steps) + k_local.shape)
+    total, (positive, aux) = jax.lax.scan(
+        client, total, (xs, ys_train, local_w, local_keys, a, meets))
+    include = positive & meets
+    with jax.named_scope("fl.aggregate"):
+        if scheme == "ideal":
+            _, mass = fedavg_weights(d_units, include)
+            any_included = jnp.any(include)
+        else:
+            _, _, mass = eq3_weights(d_units, v, ops["epsilon"], include,
+                                     server_ok)
+            any_included = jnp.any(include) | server_ok
+        new_params = jax.tree_util.tree_map(
+            lambda t, old: jnp.where(any_included,
+                                     t / jnp.maximum(mass, 1e-9), old),
+            total, params)
+    extra = jax.tree_util.tree_map(
+        lambda tw, cl: jnp.concatenate([tw[None], cl]), twin_aux, aux)
+    return new_params, positive, extra
+
+
 # ---------------------------------------------------------------------------
 # round (trace-safe body + legacy eager wrapper)
 # ---------------------------------------------------------------------------
@@ -280,6 +461,14 @@ def _round_body(state: FLState, data: FedData, phys, ops: Dict, scheme: str,
     scalars (lr / epsilon / roni_threshold / samples_per_unit / weights).
     Returns (new_state, metrics) with metrics a dict of ARRAYS — under
     ``lax.scan`` they stack into the (R, ...) history.
+
+    ``logits_fn`` is the client model: a classifier's logits function,
+    whose selected clients train at once (``_train_clients_vmapped``), or
+    a client model object that trains one client at a time
+    (``scan_clients``, e.g. ``models.nemotron_h.LMClient``: its loss,
+    accuracy and twin deviation; ``_train_clients_scanned``), whose loss
+    aux joins the metrics.  The stages run under the named scopes
+    ``fl.local_sgd``, ``fl.twin_sgd``, ``fl.roni`` and ``fl.aggregate``.
 
     ``fops`` (a ``FaultOps`` pytree, or None) switches on the fault
     engine (``repro.core.faults``): adaptive/duty-cycled poisoning gated
@@ -336,26 +525,14 @@ def _round_body(state: FLState, data: FedData, phys, ops: Dict, scheme: str,
         attacking = attack_active(fops, data.poisoned[sel_sorted],
                                   z_all[sel_sorted], jnp.median(z_all),
                                   state.round)
-        ys_train = jnp.where(attacking[:, None], data.y_train[sel_sorted],
+        gate = attacking.reshape((-1,) + (1,) * (data.y.ndim - 1))
+        ys_train = jnp.where(gate, data.y_train[sel_sorted],
                              data.y[sel_sorted])
     msk = data.mask[sel_sorted]
     map_mask = split_mapping_mask(k_map, msk, v)      # True = mapped to DT
     if scheme == "ideal":
         map_mask = jnp.zeros_like(map_mask)
     local_w = (msk & ~map_mask).astype(jnp.float32)
-
-    # 5a. local SGD (poisoners flip labels locally)
-    client_params = local_train_all(logits_fn, state.params, xs, ys_train,
-                                    local_w, local_steps, ops["lr"])
-    # 5b. server/DT SGD on mapped data (ε feature deviation).  The twin
-    # mirrors the client's data AS-IS — a poisoner's mapped samples carry
-    # the flipped labels too (DT offers no anti-poison oracle; DESIGN.md §8)
-    n, cap, dim = xs.shape
-    x_dt = dt_feature_noise(k_dt, xs, ops["epsilon"]).reshape(n * cap, dim)
-    server_params = sgd_train(logits_fn, state.params, x_dt,
-                              ys_train.reshape(-1),
-                              map_mask.reshape(-1).astype(jnp.float32),
-                              server_steps, ops["lr"])
 
     # 6. straggler deadline check (tolerance: the leader schedules
     # deadline-EXACT finishes, so `<=` would coin-flip on float error).
@@ -370,39 +547,17 @@ def _round_body(state: FLState, data: FedData, phys, ops: Dict, scheme: str,
     if fops is not None:
         meets = meets & alive            # a dropped update never arrives
 
-    # 7. RONI
-    if use_roni:
-        # per-update RONI against the pre-round global model (Biscotti [31]);
-        # the DT/server update is validated the same way — the twin mirrors
-        # poisoned mapped data too
-        positive, acc_base, _ = roni_filter(client_params, state.params,
-                                            d_units, v, ops["epsilon"],
-                                            logits_fn, data.x_val,
-                                            data.y_val,
-                                            ops["roni_threshold"])
-        server_ok = (acc_base[0]
-                     - _val_acc(logits_fn, data.x_val, data.y_val,
-                                server_params)) <= ops["roni_threshold"]
-    else:
-        positive = jnp.ones((n_selected,), bool)
-        server_ok = jnp.asarray(True)
-    include = positive & meets
-
-    # 8. aggregation (Eq. 3); ideal uses plain FedAvg on full local data.
-    # If RONI rejected EVERYTHING this round, keep the previous global model
-    # (an empty aggregate would zero the parameters) — a jnp.where over the
-    # parameter pytree, so the decision stays on-device inside the scan.
-    if scheme == "ideal":
-        agg = fedavg(client_params, d_units, include_mask=include)
-        any_included = jnp.any(include)
-    else:
-        agg = dt_aggregate(client_params, server_params, d_units, v,
-                           ops["epsilon"], include_mask=include,
-                           server_include=server_ok)
-        any_included = jnp.any(include) | server_ok
-    new_params = jax.tree_util.tree_map(
-        lambda new, old: jnp.where(any_included, new, old),
-        agg, state.params)
+    # 5, 7, 8: local and twin SGD, RONI, Eq. (3); a model too large to
+    # train a copy per client at once is trained one client at a time
+    scanned = _scans_clients(logits_fn)
+    train = _train_clients_scanned if scanned else _train_clients_vmapped
+    new_params, positive, extra = train(
+        logits_fn, state.params, data, xs, ys_train, local_w, map_mask,
+        meets, d_units, v, ops, k_dt, scheme, use_roni, local_steps,
+        server_steps)
+    val_acc = (logits_fn.accuracy(new_params, data.x_val, data.y_val)
+               if scanned else
+               _val_acc(logits_fn, data.x_val, data.y_val, new_params))
 
     # 9. reputation bookkeeping (a dropped client's verdict is not
     # recorded — the server never received an update to judge)
@@ -413,7 +568,7 @@ def _round_body(state: FLState, data: FedData, phys, ops: Dict, scheme: str,
     metrics = {
         "round": state.round,
         "selected": sel_sorted,
-        "val_acc": _val_acc(logits_fn, data.x_val, data.y_val, new_params),
+        "val_acc": val_acc,
         "latency": alloc.t_total,
         "energy": alloc.energy,
         "total_cost": alloc.t_total + alloc.energy,
@@ -422,6 +577,7 @@ def _round_body(state: FLState, data: FedData, phys, ops: Dict, scheme: str,
         "n_poisoned_selected":
             jnp.sum(data.poisoned[sel_sorted]).astype(jnp.int32),
         "mean_v": jnp.mean(v),
+        **extra,
     }
     if fops is not None:
         metrics["n_dropped"] = jnp.sum(~alive).astype(jnp.int32)
@@ -722,6 +878,7 @@ def _sweep_training_jit(phys, states, data, ops, fops, *, rounds,
     dataset for every cell), ``"seed"`` (leading [S] axis, shared across
     configs) or ``"config"`` (leading [C] axis, shared across seeds)."""
     TRACE_COUNTS["sweep_training"] += 1
+    scanned = _scans_clients(static["logits_fn"])
 
     def grid(ph_c, sts, dt, op_c, fo_c):
         def per_config(ph, st_s, d_c, op, fo):
@@ -732,10 +889,22 @@ def _sweep_training_jit(phys, states, data, ops, fops, *, rounds,
 
                 return jax.lax.scan(body, st, None, length=rounds)
 
+            if scanned:
+                # one grid cell at a time, as one client at a time
+                if data_mode == "seed":
+                    return jax.lax.map(lambda a: scan_cell(*a), (st_s, d_c))
+                return jax.lax.map(lambda st: scan_cell(st, d_c), st_s)
             if data_mode == "seed":
                 return jax.vmap(scan_cell)(st_s, d_c)      # d_c is [S, ...]
             return jax.vmap(lambda st: scan_cell(st, d_c))(st_s)
 
+        if scanned:
+            # the states are shared by the C points (not broadcast to them)
+            if data_mode == "config":
+                return jax.lax.map(lambda a: per_config(a[0], sts, *a[1:]),
+                                   (ph_c, dt, op_c, fo_c))
+            return jax.lax.map(lambda a: per_config(a[0], sts, dt, *a[1:]),
+                               (ph_c, op_c, fo_c))
         data_in = 0 if data_mode == "config" else None
         return jax.vmap(per_config, in_axes=(0, 0, data_in, 0, 0))(
             ph_c, sts, dt, op_c, fo_c)
@@ -793,6 +962,9 @@ def sweep_training(states: FLState, data: FedData, fls, games,
              only compile keys.
     games  : C ``GameConfig`` points (or a single one); their eleven
              physics floats are stacked into a [C]-leaved ``GamePhysics``.
+    logits_fn : the client model (see ``_round_body``).  A model trained
+             one client at a time also runs its grid one cell at a time
+             (``lax.map``) on one device, the C points sharing the states.
     states : ``FLState`` with a leading S seed axis (``stack_states``),
              shared across the config axis.
     data   : shared ``FedData`` (``x.ndim == 3``), or one with a leading
@@ -855,9 +1027,13 @@ def sweep_training(states: FLState, data: FedData, fls, games,
     s = jax.tree_util.tree_leaves(states)[0].shape[0]
 
     # the states grid is TRUE 2D — [C, S, ...] leaves, configs outer,
-    # seeds inner — so it tiles directly onto the (cfg, draw) device mesh
-    bcast_cfg = lambda x: jnp.broadcast_to(x[None], (c,) + x.shape)
-    states = jax.tree_util.tree_map(bcast_cfg, states)
+    # seeds inner — so it tiles directly onto the (cfg, draw) device mesh.
+    # A model trained one client at a time runs its grid one cell at a time
+    # on one device, the C points sharing the [S] states: no copy per point
+    scanned = _scans_clients(logits_fn)
+    if not scanned:
+        bcast_cfg = lambda x: jnp.broadcast_to(x[None], (c,) + x.shape)
+        states = jax.tree_util.tree_map(bcast_cfg, states)
     if data.x.ndim == 4:
         data_mode = data_axis
         if data_axis == "config" and data.x.shape[0] != c:
@@ -870,7 +1046,7 @@ def sweep_training(states: FLState, data: FedData, fls, games,
 
     # multi-device: pad the grid to the (dc, dk) mesh factorization with
     # edge-replicated cells (sliced back off below) and place the shards
-    grid = game_mesh.grid_layout(c, s)
+    grid = (1, 1) if scanned else game_mesh.grid_layout(c, s)
     dc, dk = grid
     if dc * dk > 1:
         cp = game_mesh.padded_size(c, dc)

@@ -24,6 +24,12 @@ import jax.numpy as jnp
 from .aggregation import dt_aggregate
 
 
+def roni_verdict(acc_base, acc_update, threshold):
+    """Positive (the update is kept) where it costs the validation accuracy
+    at most ``threshold``; a larger drop is a negative interaction."""
+    return (acc_base - acc_update) <= threshold
+
+
 @partial(jax.jit, static_argnames=("logits_fn",))
 def roni_filter(client_params, global_params, d_sizes, v, epsilon,
                 logits_fn: Callable, x_val, y_val, threshold: float = 0.02):
@@ -46,5 +52,5 @@ def roni_filter(client_params, global_params, d_sizes, v, epsilon,
     acc_update = jax.vmap(
         lambda i: acc(jax.tree_util.tree_map(lambda c: c[i], client_params))
     )(jnp.arange(n))
-    positive = (acc_base - acc_update) <= threshold
+    positive = roni_verdict(acc_base, acc_update, threshold)
     return positive, jnp.full((n,), acc_base), acc_update

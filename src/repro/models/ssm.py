@@ -70,7 +70,9 @@ def ssd_chunked(x, dt, A, B, C, chunk: int, return_state: bool = False):
     Implemented as ONE ``lax.scan`` over chunks carrying the SSM state —
     the quadratic intra-chunk term is materialized for a single chunk at a
     time ([b,l,l,h], a few MB), matching what the Pallas kernel holds in
-    VMEM.  All decay math in f32.
+    VMEM; the backward pass recomputes each chunk from its carried state
+    instead of keeping every chunk's intermediates.  All decay math in
+    f32.
     """
     b, s, h, p = x.shape
     pad = (-s) % chunk
@@ -92,7 +94,10 @@ def ssd_chunked(x, dt, A, B, C, chunk: int, return_state: bool = False):
 
         # intra-chunk (quadratic) term
         Lmat = dA_cs[:, :, None, :] - dA_cs[:, None, :, :]  # [b,l,m,h]
-        Lmat = jnp.where(causal[None, :, :, None], jnp.exp(Lmat), 0.0)
+        # mask before the exp: above the diagonal the exponent grows with
+        # the chunk's total decay, and an exp that overflows there would
+        # turn the masked gradient into 0 * inf = NaN
+        Lmat = jnp.exp(jnp.where(causal[None, :, :, None], Lmat, -jnp.inf))
         CB = jnp.einsum("blhn,bmhn->blmh", Cr.astype(jnp.float32),
                         Br.astype(jnp.float32))
         gate = CB * Lmat * dtr[:, None, :, :]
@@ -113,8 +118,8 @@ def ssd_chunked(x, dt, A, B, C, chunk: int, return_state: bool = False):
         a.reshape((b, c, l) + a.shape[2:]), 1, 0)
     init = jnp.zeros((b, h, p, B.shape[-1]), jnp.float32)
     final_state, ys = jax.lax.scan(
-        per_chunk, init, (to_chunks(x), to_chunks(dt), to_chunks(B),
-                          to_chunks(C)))
+        jax.checkpoint(per_chunk), init,
+        (to_chunks(x), to_chunks(dt), to_chunks(B), to_chunks(C)))
     y = jnp.moveaxis(ys, 0, 1).reshape(b, s, h, p)
     if pad:
         y = y[:, :s - pad]
