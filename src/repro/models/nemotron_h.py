@@ -18,8 +18,8 @@ The stack follows ``hybrid_override_pattern``: one mixer per layer, each
     ``experts_held``), as one chip of an expert-parallel deployment does: it
     routes over all of them and computes only its own experts' terms, for
     every token routed to them (no capacity: the assignments are sorted by
-    expert and each expert multiplies its own tokens, block by block, as
-    many blocks as its tokens fill).
+    expert, and one grouped matmul, a Pallas TPU kernel, multiplies each
+    expert's tokens over only the row tiles they fill).
   * ``*`` grouped-query attention, causal, no bias and no positional
     encoding (the Mamba layers carry position).
 
@@ -39,11 +39,16 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
+from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm, tgmm
 
 from .ssm import ssd_chunked
 
 MAMBA, MOE, ATTN = "M", "E", "*"
 ATTN_BLOCK = 512       # queries per attention block
+# (tm, tk, tn) of the grouped expert matmul, chosen on a v5e by timing one
+# MoE layer's forward and backward at the LM cell's shape: 42.1 ms, against
+# 42.3 at (512, 512, 512); a gather-only combine read 49.7-51.3 ms
+GMM_TILING = (256, 512, 512)
 
 
 @dataclass(frozen=True)
@@ -163,24 +168,63 @@ def _relu2(x):
     return r * r
 
 
-def expert_rows(tokens: int, cfg: HybridConfig) -> int:
-    """Rows of one block of an expert's grouped matmul: about the expected
-    number of tokens a held expert receives, in whole tiles of 128, at
-    most all the tokens."""
-    expect = tokens * cfg.top_k / cfg.n_experts
-    return min(tokens, 128 * max(1, math.ceil(expect / 128)))
+@partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def grouped_matmul(x, w, sizes, tiling, interpret):
+    """Rows of x [m, k] laid end to end in groups of ``sizes`` rows, group
+    g's times w[g] [k, n] (cast to x's dtype), accumulated and returned in
+    float32: megablox's Pallas TPU kernel over only the m-tiles the groups
+    fill; rows past ``sum(sizes)`` are left unwritten.  In the backward
+    pass the same kernel (``transpose_rhs``) gives x's cotangent and its
+    transpose (``tgmm``) w's, over the same tiles, from the cotangent in
+    x's dtype (as a dot at the default precision takes it)."""
+    return gmm(x, w.astype(x.dtype), sizes, jnp.float32, tiling,
+               interpret=interpret)
+
+
+def _grouped_matmul_fwd(x, w, sizes, tiling, interpret):
+    w = w.astype(x.dtype)
+    return grouped_matmul(x, w, sizes, tiling, interpret), (x, w, sizes)
+
+
+def _grouped_matmul_bwd(tiling, interpret, res, g):
+    x, w, sizes = res
+    g = g.astype(x.dtype)
+    dx = gmm(g, w, sizes, x.dtype, tiling, transpose_rhs=True,
+             interpret=interpret)
+    dw = tgmm(x.T, g, sizes, jnp.float32, tiling,
+              num_actual_groups=w.shape[0], interpret=interpret)
+    return dx, dw, None
+
+
+grouped_matmul.defvjp(_grouped_matmul_fwd, _grouped_matmul_bwd)
+
+
+def _expert_tiles(counts, tm: int):
+    """m-tiles the grouped matmul runs for groups of ``counts`` rows laid
+    end to end: each non-empty group from its start rounded down to a tile
+    to its end rounded up, as megablox's group metadata counts them."""
+    ends = jnp.cumsum(counts)
+    starts = ends - counts
+    return jnp.sum(jnp.where(counts > 0, -(-ends // tm) - starts // tm, 0))
 
 
 def moe_mixer(p, x, cfg: HybridConfig):
-    """Returns (y, tokens per held expert [held], tokens dropped).
+    """Returns (y, {"expert_tokens": tokens per held expert [held],
+    "expert_tiles": m-tiles the grouped matmul ran, "tokens_dropped":
+    assignments to held experts left uncomputed}).
 
-    The assignments to held experts are sorted by expert; each expert then
-    multiplies its own tokens in blocks of ``expert_rows``, dense matmuls
-    of a block by its weights.  The loop runs over as many blocks as an
-    expert could need (every token routed to it) and skips a block past
-    the expert's last assignment, so the work follows the routing.  No
-    token is dropped: the blocks cover every count, so the third value is
-    0 by construction, an invariant the benchmark's check holds it to."""
+    The assignments to held experts are sorted by expert and their tokens
+    gathered into one matrix of a static ``T·min(top_k, held)`` rows (a
+    bound no routing exceeds, so there is no capacity); one grouped matmul
+    (megablox ``gmm``, a Pallas TPU kernel, in interpret mode off the TPU)
+    multiplies each expert's rows by its weights, over only the m-tiles
+    that the experts' rows fill, and its custom VJP does the same for the
+    gradients.  The kernel leaves rows past the held assignments unwritten,
+    in its output and in its input's cotangent, so both sides are masked
+    with ``where`` (a multiply by 0 would keep a NaN).  The weighted rows
+    are added back into their tokens.  No token is dropped: the row bound
+    covers every count, so the third value is 0 by construction, an
+    invariant the benchmark's check holds it to."""
     b, s, d = x.shape
     t, k, e = b * s, cfg.top_k, cfg.held
     xt = x.reshape(t, d)
@@ -191,52 +235,24 @@ def moe_mixer(p, x, cfg: HybridConfig):
     group = jnp.where(mine, local, e).reshape(-1)
     order = jnp.argsort(group, stable=True)
     counts = jnp.zeros((e + 1,), jnp.int32).at[group].add(1)[:e]
-    ends = jnp.cumsum(counts)
-    starts = ends - counts
-    rows = expert_rows(t, cfg)
-    blocks = -(-t // rows)             # an expert takes each token at most once
-    token = jnp.pad(order // k, (0, rows))
-    weight = jnp.pad(jnp.where(mine, w, 0.0).reshape(-1)[order], (0, rows))
+    tm = GMM_TILING[0]
+    m = -(-t * min(k, e) // tm) * tm
+    order = jnp.pad(order, (0, max(0, m - t * k)))[:m]
+    held = jnp.sum(counts)
+    live = (jnp.arange(m) < held)[:, None]
+    token = order // k
+    weight = jnp.where(mine, w, 0.0).reshape(-1)[order]
     dt = cfg.compute_dtype
-
-    def expert(out, inp):
-        up, down, lo, hi = inp
-
-        @jax.checkpoint
-        def block(j):
-            at = lo + j * rows
-            live = at < hi
-
-            def compute():
-                tok = jax.lax.dynamic_slice_in_dim(token, at, rows)
-                wt = jax.lax.dynamic_slice_in_dim(weight, at, rows)
-                ok = (at + jnp.arange(rows) < hi)[:, None]
-                xs = jnp.where(ok, xt[tok], 0).astype(dt)
-                hid = jnp.matmul(xs, up.astype(dt),
-                                 preferred_element_type=jnp.float32)
-                y = jnp.matmul(_relu2(hid).astype(dt), down.astype(dt),
-                               preferred_element_type=jnp.float32)
-                return tok, jnp.where(ok, y * wt[:, None], 0.0)
-
-            def skip():
-                return (jnp.zeros((rows,), token.dtype),
-                        jnp.zeros((rows, d), jnp.float32))
-            tok, y = jax.lax.cond(live, compute, skip)
-            done = jnp.where(live, jnp.minimum(rows, hi - at), 0)
-            return tok, y, done
-
-        def step(out, j):
-            tok, y, done = block(j)
-            return out.at[tok].add(y), done
-
-        out, done = jax.lax.scan(step, out, jnp.arange(blocks))
-        return out, jnp.sum(done)
-
-    routed, done = jax.lax.scan(expert, jnp.zeros((t, d), jnp.float32),
-                                (p["up"], p["down"], starts, ends))
+    mm = partial(grouped_matmul, sizes=counts, tiling=GMM_TILING,
+                 interpret=jax.default_backend() != "tpu")
+    xs = jnp.where(live, xt.astype(dt)[token], 0)
+    y = mm(_relu2(mm(xs, p["up"])).astype(dt), p["down"])
+    y = jnp.where(live, y, 0.0) * weight[:, None]
+    routed = jnp.zeros((t, d), jnp.float32).at[token].add(y)
     shared = _mm(_relu2(_mm(xt, p["shared_up"], cfg)), p["shared_down"], cfg)
-    y = (routed + shared).reshape(b, s, d)
-    return y, counts, jnp.sum(counts) - jnp.sum(done)
+    return (routed + shared).reshape(b, s, d), {
+        "expert_tokens": counts, "expert_tiles": _expert_tiles(counts, tm),
+        "tokens_dropped": held - jnp.minimum(held, m)}
 
 
 def attn_mixer(p, x, cfg: HybridConfig):
@@ -278,30 +294,35 @@ def _layer(kind, p, x, cfg):
             return x + mamba_mixer(p, h, cfg).astype(x.dtype), None
     if kind == MOE:
         with jax.named_scope("lm.moe"):
-            y, counts, dropped = moe_mixer(p, h, cfg)
-            return x + y.astype(x.dtype), (counts, dropped)
+            y, aux = moe_mixer(p, h, cfg)
+            return x + y.astype(x.dtype), aux
     with jax.named_scope("lm.attn"):
         return x + attn_mixer(p, h, cfg).astype(x.dtype), None
 
 
 def hidden(params, tokens, cfg: HybridConfig):
-    """tokens [B, S] -> (final-normed hidden [B, S, D], tokens per held
-    expert [n_moe, held], tokens dropped)."""
+    """tokens [B, S] -> (final-normed hidden [B, S, D], the MoE layers'
+    counters: ``expert_tokens`` [n_moe, held], ``expert_tiles`` [n_moe],
+    ``tokens_dropped`` summed over the layers)."""
     x = params["embed"].astype(cfg.compute_dtype)[tokens]
-    counts, dropped = [], jnp.zeros((), jnp.int32)
+    auxes = []
     for kind, p in zip(cfg.pattern, params["layers"]):
         # each layer is recomputed in the backward pass
         x, aux = jax.checkpoint(partial(_layer, kind, cfg=cfg))(p, x)
         if aux is not None:
-            counts.append(aux[0])
-            dropped = dropped + aux[1]
-    e = cfg.held
-    counts = jnp.stack(counts) if counts else jnp.zeros((0, e), jnp.int32)
-    return rmsnorm(x, params["norm_f"], cfg.norm_eps), counts, dropped
+            auxes.append(aux)
+    if auxes:
+        aux = {f: jnp.stack([a[f] for a in auxes]) for f in auxes[0]}
+    else:
+        aux = {"expert_tokens": jnp.zeros((0, cfg.held), jnp.int32),
+               "expert_tiles": jnp.zeros((0,), jnp.int32),
+               "tokens_dropped": jnp.zeros((0,), jnp.int32)}
+    aux["tokens_dropped"] = jnp.sum(aux["tokens_dropped"])
+    return rmsnorm(x, params["norm_f"], cfg.norm_eps), aux
 
 
 def logits(params, tokens, cfg: HybridConfig):
-    h, _, _ = hidden(params, tokens, cfg)
+    h, _ = hidden(params, tokens, cfg)
     with jax.named_scope("lm.head"):
         return _mm(h, params["head"], cfg)
 
@@ -310,15 +331,16 @@ def loss(params, x, y, w, cfg: HybridConfig):
     """Mean next-token cross-entropy over the tokens of the sequences of
     weight 1 (x, y: [B, S] inputs and targets, w: [B]); returns (loss,
     {"expert_tokens": tokens per held expert [n_moe, held],
+    "expert_tiles": m-tiles of the grouped expert matmul [n_moe],
     "tokens_dropped": assignments to held experts left uncomputed})."""
-    h, counts, dropped = hidden(params, x, cfg)
+    h, aux = hidden(params, x, cfg)
     with jax.named_scope("lm.head"):
         z = _mm(h, params["head"], cfg)
         lse = jax.nn.logsumexp(z, axis=-1)
         nll = lse - jnp.take_along_axis(z, y[..., None], axis=-1)[..., 0]
         total = jnp.sum(jnp.sum(nll, -1) * w)
         value = total / jnp.maximum(jnp.sum(w) * y.shape[1], 1.0)
-    return value, {"expert_tokens": counts, "tokens_dropped": dropped}
+    return value, aux
 
 
 def accuracy(params, x, y, cfg: HybridConfig, batch: int):

@@ -96,6 +96,7 @@ def test_forward_loss_and_gradients_equal_the_reference(setup, monkeypatch):
     assert abs(float(l) - float(l_ref)) <= 1e-5 * abs(float(l_ref))
     np.testing.assert_array_equal(aux["expert_tokens"], counts)
     assert int(aux["tokens_dropped"]) == 0
+    assert aux["expert_tiles"].shape == (1,)
     for a, b in zip(jax.tree_util.tree_leaves(g),
                     jax.tree_util.tree_leaves(g_ref)):
         assert float(jnp.linalg.norm(a - b)) <= 1e-3 * max(
@@ -128,39 +129,122 @@ def test_expert_shares_add_up_to_the_uncut_layer():
             mc("E", held=16))))
     with jax.default_matmul_precision("highest"):
         halves, shared, (whole, counts) = parts(full, x)
-    assert all(int(h[2]) == 0 for h in halves)
+    assert all(int(h[1]["tokens_dropped"]) == 0 for h in halves)
     total = halves[0][0] + halves[1][0] - shared
     assert float(jnp.max(jnp.abs(total - whole))) <= 1e-4 * float(
         jnp.max(jnp.abs(whole)))
     np.testing.assert_array_equal(
-        jnp.concatenate([halves[0][1], halves[1][1]]), counts)
+        jnp.concatenate([halves[0][1]["expert_tokens"],
+                         halves[1][1]["expert_tokens"]]), counts)
 
 
-@pytest.mark.parametrize("seq", [32, 512])
+def _routed_to(p, *experts):
+    """``p`` with a router that gives every token the same scores, and a
+    correction bias that makes ``experts`` its top choices."""
+    bias = jnp.zeros((16,)).at[jnp.asarray(experts)].set(1.0)
+    return dict(p, router=jnp.zeros_like(p["router"]), bias=bias)
+
+
+def _held_params(key, **changes):
+    p = dict(_moe_params(key))
+    return dict(p, up=p["up"][:8], down=p["down"][:8], **changes)
+
+
+@pytest.mark.parametrize("seq", [32, 512])  # within one m-tile, across
 def test_every_token_to_one_held_expert_is_computed(seq):
     """A router that sends every token to held expert 3 (and, the top 2
     being taken, to expert 0 with it): no assignment is dropped, whether
-    the expert's tokens fill one block of its grouped matmul (64 tokens in
-    a block of 64) or eight (1024 in blocks of 128), and the output is the
-    reference's."""
+    expert 3's tokens lie within one m-tile of the grouped matmul (64
+    tokens) or span several (1024), and the output is the reference's."""
     c = mc("E", held=8)
-    p = dict(_moe_params(jax.random.PRNGKey(5)))
-    p = dict(p, up=p["up"][:8], down=p["down"][:8],
-             router=jnp.zeros_like(p["router"]),
-             bias=jnp.zeros((16,)).at[3].set(1.0))
+    p = _routed_to(_held_params(jax.random.PRNGKey(5)), 3)
     x = jax.random.normal(jax.random.PRNGKey(6), (2, seq, 64))
-    t = 2 * seq
-    assert -(-t // nh.expert_rows(t, program(c))) == (1 if seq == 32 else 8)
+    t, tm = 2 * seq, nh.GMM_TILING[0]
+    spans = -(-2 * t // tm) - t // tm        # m-tiles of expert 3's rows
+    assert (spans == 1) if seq == 32 else (spans >= 2)
     both = jax.jit(lambda p, x: (
         nh.moe_mixer(p, x, program(c)),
         ref._moe(p, x, ref._thaw(ref._freeze(c)))))
     with jax.default_matmul_precision("highest"):
-        (y, counts, dropped), (y_ref, counts_ref) = both(p, x)
-    assert int(dropped) == 0
+        (y, aux), (y_ref, counts_ref) = both(p, x)
+    counts = aux["expert_tokens"]
+    assert int(aux["tokens_dropped"]) == 0
     assert int(counts[3]) == t and int(jnp.sum(counts)) == 2 * t
     np.testing.assert_array_equal(counts, counts_ref)
     assert float(jnp.max(jnp.abs(y - y_ref))) <= 1e-4 * float(
         jnp.max(jnp.abs(y_ref)))
+
+
+@pytest.mark.parametrize("case", ["mostly_elsewhere", "none_held"])
+def test_few_or_no_held_assignments_give_the_reference_and_its_gradients(
+        case):
+    """Most assignments go to experts 8-15, which this layer does not
+    hold, so the held ones end part-way into an m-tile (the kernel leaves
+    the rows past them unwritten, in its output and in its input's
+    cotangent); or every one does, and the kernel runs no tile.  The loss
+    and its gradients with respect to x, ``up`` and ``down`` are finite
+    and the reference's."""
+    c = mc("E", held=8)
+    p = _held_params(jax.random.PRNGKey(8))
+    if case == "mostly_elsewhere":
+        p["bias"] = jnp.zeros((16,)).at[8:].set(0.03)
+    else:
+        p = _routed_to(p, 8, 9)
+    x = jax.random.normal(jax.random.PRNGKey(9), (2, 32, 64))
+    r = jax.random.normal(jax.random.PRNGKey(10), (2, 32, 64))
+    cfg, rc = program(c), ref._thaw(ref._freeze(c))
+
+    def grads(mixer):
+        def f(up, down, x):
+            y, aux = mixer(dict(p, up=up, down=down), x)
+            return jnp.vdot(y, r), aux
+        return jax.value_and_grad(f, (0, 1, 2), has_aux=True)(
+            p["up"], p["down"], x)
+    both = jax.jit(lambda: (grads(lambda q, x: nh.moe_mixer(q, x, cfg)),
+                            grads(lambda q, x: ref._moe(q, x, rc))))
+    with jax.default_matmul_precision("highest"):
+        ((l, aux), g), ((l_ref, counts), g_ref) = both()
+    held, tm = int(jnp.sum(counts)), nh.GMM_TILING[0]
+    if case == "mostly_elsewhere":        # of 2 x 32 tokens x top 2
+        assert 0 < held < 64 and held % tm != 0
+    else:
+        assert held == 0 and int(aux["expert_tiles"]) == 0
+    np.testing.assert_array_equal(aux["expert_tokens"], counts)
+    assert int(aux["tokens_dropped"]) == 0
+    assert abs(float(l) - float(l_ref)) <= 1e-5 * abs(float(l_ref))
+    for a, b in zip(g, g_ref):
+        assert bool(jnp.all(jnp.isfinite(a)))
+        assert float(jnp.linalg.norm(a - b)) <= 1e-3 * max(
+            float(jnp.linalg.norm(b)), 1e-12)
+
+
+def test_expert_tiles_are_counted_by_hand(monkeypatch):
+    """Every token to experts 0 and 3 (400 tokens each), in m-tiles of
+    128: expert 0's rows 0-399 fill tiles 0-3, expert 3's rows 400-799
+    tiles 3-6, which the kernel visits once for each expert: 8 tiles."""
+    monkeypatch.setattr(nh, "GMM_TILING", (128, 128, 128))
+    c = mc("E", held=8)
+    p = _routed_to(_held_params(jax.random.PRNGKey(11)), 3)
+    x = jax.random.normal(jax.random.PRNGKey(12), (2, 200, 64))
+    _, aux = jax.jit(lambda p, x: nh.moe_mixer(p, x, program(c)))(p, x)
+    assert [int(n) for n in aux["expert_tokens"]] == [400, 0, 0, 400, 0,
+                                                       0, 0, 0]
+    assert int(aux["expert_tiles"]) == 8
+
+
+@pytest.mark.parametrize("counts", [[400, 0, 0, 400], [0, 5, 128, 0, 1],
+                                    [0, 0, 0], [127, 1, 128, 300]])
+def test_expert_tiles_are_the_kernels_own_count(counts):
+    """``expert_tiles`` is the number of m-tiles megablox's own group
+    metadata gives the grouped matmul, for groups that start and end
+    inside tiles, on tile edges, or are empty."""
+    from jax.experimental.pallas.ops.tpu.megablox.gmm import (
+        make_group_metadata)
+    sizes = jnp.asarray(counts, jnp.int32)
+    _, tiles = make_group_metadata(
+        group_sizes=sizes, m=1024, tm=128, start_group=jnp.int32(0),
+        num_nonzero_groups=len(counts), visit_empty_groups=False)
+    assert int(nh._expert_tiles(sizes, 128)) == int(tiles)
 
 
 def test_client_model_is_trained_one_client_at_a_time(setup):
@@ -232,12 +316,18 @@ def test_lm_round_traces_once_and_keeps_its_scopes():
         fl_round._sweep_training_jit = real
     (args, kwargs), = seen
     before = TRACE_COUNTS["run_round"]
-    text = real.lower(*args, **kwargs).compile().as_text()
+    lowered = real.lower(*args, **kwargs)
+    text = lowered.compile().as_text()
     assert TRACE_COUNTS["run_round"] - before == 1
     for scope in ("lm.mamba", "lm.moe", "lm.attn", "lm.head",
                   "fl.local_sgd", "fl.twin_sgd", "fl.roni", "fl.aggregate",
                   "sic_power"):
         assert scope in text, scope
+    # the MoE counters join the metrics history: [..., R, N + 1, steps, n_moe]
+    _, history = lowered.out_info
+    tiles, tokens = history["expert_tiles"], history["expert_tokens"]
+    assert tiles.shape[-4:] == (2, 4, 2, 1)
+    assert tokens.shape[-5:] == (2, 4, 2, 1, c["n_routed_experts"])
 
 
 def test_gradients_stay_finite_where_a_chunk_decays_far(setup):

@@ -2,8 +2,9 @@
 
 The TPU compiler is installed with JAX, and compiles for a chip that is
 described rather than attached.  These tests compile the main path's
-Pallas kernel — the SIC suffix scan — and the large-N engine around it,
-and assert that the kernel is in the program as a Mosaic custom call
+Pallas kernels — the SIC suffix scan, with the large-N engine around it,
+and the LM client's grouped expert matmul — and assert that the kernel
+is in the program as a Mosaic custom call
 (``tpu_custom_call``): what the interpreter-mode tests cannot show is
 whether Mosaic accepts the tiling, the dot and the scratch layout.
 
@@ -13,7 +14,9 @@ and every test worker imports every test file.  The persistent compile
 cache is off around these compiles (an entry written for a described
 chip cannot be read back without one).
 """
+import dataclasses
 import os
+from functools import partial
 
 import pytest
 
@@ -21,8 +24,10 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import SingleDeviceSharding
 
+from repro.configs.nemotron3_nano_30b_a3b import config as nemotron
 from repro.core import stackelberg as sb
 from repro.kernels import ops
+from repro.models import nemotron_h as nh
 from repro.kernels.sic_suffix import sic_suffix_pallas
 
 
@@ -83,4 +88,27 @@ def test_blocked_pallas_engine_compiles_for_v5e(one_chip, monkeypatch):
         _sds((k, n), one_chip), _sds((), one_chip), _sds((), one_chip),
         max_iter=20, inner="projected", sic_mode="blocked_pallas",
         shards=1).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_moe_layer_grouped_matmul_compiles_for_v5e(one_chip, monkeypatch):
+    """One MoE layer of the LM cell, forward and backward under the
+    layer's checkpoint, at the published widths (d_model 2688, 8 held
+    experts of width 1856 routed top-6 of 128, a shared expert of 3712)
+    over 2 x 512 tokens: Mosaic takes ``GMM_TILING`` for the grouped
+    matmul and its transposes (``gmm``, ``tgmm``) within the fast memory
+    a kernel may use.  The host here is a CPU, so the backend test that
+    picks the interpreter is steered to the TPU for this compile only."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = dataclasses.replace(nemotron(), pattern="E", experts_held=8)
+    d, f, fs, e = cfg.d_model, cfg.expert_ff, cfg.shared_ff, cfg.n_experts
+    p = {name: _sds(shape, one_chip) for name, shape in {
+        "norm": (d,), "router": (d, e), "bias": (e,), "up": (8, d, f),
+        "down": (8, f, d), "shared_up": (d, fs), "shared_down": (fs, d)
+    }.items()}
+    layer = jax.checkpoint(partial(nh._layer, nh.MOE, cfg=cfg))
+    step = jax.jit(jax.grad(lambda p, x: jnp.sum(
+        layer(p, x)[0].astype(jnp.float32)), (0, 1)))
+    compiled = step.lower(p, _sds((2, 512, d), one_chip,
+                                  jnp.bfloat16)).compile()
     assert "tpu_custom_call" in compiled.as_text()
